@@ -6,9 +6,11 @@
 #   bash scripts/bench.sh                         # the CI artifact set
 #   bash scripts/bench.sh benchmarks.bench_serve  # one module
 #
-# Host flags (tcmalloc LD_PRELOAD when available, XLA fake-device count)
-# come from scripts/host_tune.sh and are recorded into every BENCH_*.json
-# under "host".
+# Host flags (tcmalloc LD_PRELOAD when available) come from
+# scripts/host_tune.sh and are recorded into every BENCH_*.json under
+# "host". The platform is JAX's default: the chip where there is one.
+# Modules run one after another from this shell, which never imports JAX,
+# so each process has the chip to itself.
 set -euo pipefail
 cd "$(dirname "${BASH_SOURCE[0]}")/.."
 

@@ -1,18 +1,18 @@
 #!/usr/bin/env bash
-# Host-side performance tuning for CPU benchmark runs. Source this (don't
-# execute it) from a bench entrypoint:
+# Host-side tuning for benchmark runs. Source this (don't execute it)
+# from a bench entrypoint:
 #
 #   source scripts/host_tune.sh
 #
-# Two idioms, both from large-scale JAX-on-host training setups:
+# tcmalloc via LD_PRELOAD: glibc malloc serializes the allocator under
+# XLA's multi-threaded host execution; tcmalloc's per-thread caches remove
+# that contention. Preloaded only if an installed copy is found — a bare
+# container runs unchanged.
 #
-# 1. tcmalloc via LD_PRELOAD. glibc malloc serializes the allocator under
-#    XLA's multi-threaded host execution; tcmalloc's per-thread caches
-#    remove that contention. Preloaded only if an installed copy is found
-#    — a bare container runs unchanged.
-# 2. XLA_FLAGS=--xla_force_host_platform_device_count=N so multi-device
-#    code paths (pipeline stages, data-parallel chips) actually lower on
-#    a CPU host instead of collapsing to one device.
+# The platform is left to JAX: on a TPU host the benchmarks run on the
+# chip. A CPU run with fake devices is asked for explicitly, e.g.
+#   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+#     bash scripts/bench.sh
 #
 # Everything exported here lands in the bench artifact's "host" block
 # (benchmarks/common.py host_env()), so a tuned run is distinguishable
@@ -43,8 +43,5 @@ if [[ -z "${LD_PRELOAD:-}" ]]; then
   unset _tcmalloc
 fi
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
-
 # marker: bench artifacts record that this file configured the host
-export REPRO_HOST_TUNE="tcmalloc=${LD_PRELOAD:-none};${XLA_FLAGS}"
+export REPRO_HOST_TUNE="tcmalloc=${LD_PRELOAD:-none};${XLA_FLAGS:-}"

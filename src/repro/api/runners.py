@@ -157,6 +157,7 @@ class PipelinedRunner(Runner):
             plan=raw.plan,
             extras={
                 "raw": raw,
+                "engine": trainer.engine,
                 "lam_curve": raw.lam_curve,
                 "peak_buffered_rounds": raw.peak_buffered_rounds,
                 "stream_wait_s": raw.stream_wait_s,

@@ -522,6 +522,7 @@ class FerretTrainer:
             feeder.close()
 
         self.final_params = T.merge_stage_params(self.model_cfg, list(stages))
+        self.engine = engine
         rounds = cursor
         acc = np.concatenate(acc_all) if acc_all else np.zeros(0)
         admitted = np.concatenate(adm_all) if adm_all else np.zeros(0)

@@ -338,6 +338,18 @@ class FerretEngine:
 
         return jax.lax.scan(round_fn, state, xs)
 
+    def lower(self, state, stream: Dict[str, jnp.ndarray], penalty: Pytree = None):
+        """The single-device scan ``run`` executes, lowered for these
+        arguments (arrays or ``jax.ShapeDtypeStruct``s); ``.compile()`` on
+        the result gives the program, e.g. to look for its kernels."""
+        carry, xs = self._scan_args(state, stream)
+        return self._compiled.lower(carry, xs, penalty)
+
+    def _scan_args(self, state, stream):
+        xs = dict(self._schedule_xs())
+        xs["batch"] = stream
+        return (state.as_tuple() if isinstance(state, EngineState) else state), xs
+
     def run(self, state, stream: Dict[str, jnp.ndarray], penalty: Pytree = None):
         """stream: dict of arrays stacked over rounds, e.g. tokens (R, b, s).
 
@@ -359,10 +371,8 @@ class FerretEngine:
                 "the algorithm must populate its penalty extras before the "
                 "segment runs (see OCLAlgorithm.engine_penalty_extras)"
             )
-        xs = dict(self._schedule_xs())
-        xs["batch"] = stream
+        carry, xs = self._scan_args(state, stream)
         meta = state if isinstance(state, EngineState) else None
-        carry = state.as_tuple() if meta is not None else state
         if self.mesh is not None and self.mesh.devices.size > 1:
             from repro.launch import shardings as sh
             from repro.models import shard_hints as hints_lib
@@ -377,7 +387,7 @@ class FerretEngine:
             # The mesh context resolves the hints' PartitionSpecs inside
             # the traced scan (first call traces; later calls reuse the
             # executable, the context is then just a cheap no-op).
-            with self.mesh, hints_lib.use_hints(
+            with jax.set_mesh(self.mesh), hints_lib.use_hints(
                 self.hints if self.hints is not None else hints_lib.ShardHints()
             ):
                 final, ys = self._compiled(carry, xs, penalty)

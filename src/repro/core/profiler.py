@@ -3,8 +3,9 @@
 The paper profiles wall-clock per layer on the target GPU (appendix Alg. 3,
 ``profile(θ)``). This container has no TPU, so the default profile is
 *analytic*: per-layer FLOPs and bytes are derived from the architecture
-config and converted to time with the TPU-v5e roofline
-(t = max(flops / (util · peak), bytes / hbm_bw)). A measured profile
+config and converted to time with the chip's roofline
+(t = max(flops / (util · peak), bytes / hbm_bw)), peaks from
+``repro.core.chips``. A measured profile
 (timing real CPU executions of single blocks) is also provided for the
 small benchmark models and can override the analytic one.
 """
@@ -14,12 +15,9 @@ from __future__ import annotations
 import dataclasses
 from typing import List
 
+from repro.core.chips import chip_spec
 from repro.models.config import ModelConfig
 
-# TPU v5e hardware constants (per chip) — also used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
 DEFAULT_UTILIZATION = 0.55  # achievable fraction of peak for dense matmul
 
 
@@ -125,9 +123,10 @@ def analytic_profile(
     w_b = _block_w_bytes(cfg, param_dtype_bytes) // chips
     a_b = _block_a_bytes(cfg, batch, seq, act_dtype_bytes) // chips
     a_int = _block_a_internal_bytes(cfg, batch, seq, act_dtype_bytes) // chips
+    chip = chip_spec()
 
     def t_of(flops, bytes_moved):
-        return max(flops / (utilization * PEAK_FLOPS_BF16), bytes_moved / HBM_BW)
+        return max(flops / (utilization * chip.peak_flops_bf16), bytes_moved / chip.hbm_bw)
 
     t_f = t_of(f_fwd, w_b + a_b + a_int)
     t_b = t_of(2.0 * f_fwd, 2 * (w_b + a_b + a_int))

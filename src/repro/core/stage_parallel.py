@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.models.config import ModelConfig
 
 
@@ -101,14 +100,13 @@ def spatial_pipeline_logits(
 
     M, b, s = batch["tokens"].shape
     cd = jnp.dtype(cfg.compute_dtype)
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x_all = embed_tokens(params["embed"], batch["tokens"], cd)  # (M, b, s, d)
     stage_blocks = stack_stage_blocks(cfg, params, num_stages)
 
     T = M + num_stages - 1  # wavefront ticks
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_blocks), P(None)),
         out_specs=P(None),
@@ -117,8 +115,11 @@ def spatial_pipeline_logits(
         # blocks_local leaves: (1, L/P, ...) — this device's stage
         blocks_local = jax.tree.map(lambda a: a[0], blocks_local)
         idx = jax.lax.axis_index(axis)
+        # built inside the body: shard_map may not close over arrays of a
+        # mesh with Explicit axes (what jax.make_mesh gives by default)
+        positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
         last = num_stages - 1
-        zero = pvary(jnp.zeros((b, s, cfg.d_model), cd), (axis,))
+        zero = jax.lax.pcast(jnp.zeros((b, s, cfg.d_model), cd), (axis,), to="varying")
 
         def tick(carry, t):
             buf = carry  # activation held by this stage

@@ -15,8 +15,9 @@ and norm scales to the jnp reference.  This module removes both costs:
 - ``compensate_tree`` / ``stats_tree`` run the Eq. 9 inner loop and the
   Alg. 1 λ-statistics as **one** ``pl.pallas_call`` each over the packed
   buffer — the λ-statistics s1/s2 block-reduce on-device in the same data
-  pass (per-grid-step partials, race-free on sequential and parallel
-  grids alike, plus a tiny on-device epilogue sum).  When packing is
+  pass (per-grid-step partials in SMEM on a sequential grid, plus a tiny
+  on-device epilogue sum).  The kernels themselves are the per-leaf ones
+  of ``repro.kernels.iter_fisher``, launched once over the packed buffer.  When packing is
   forced without Pallas (``REPRO_PACK=1`` on CPU), the same packed buffer
   goes through the jnp reference in one fused elementwise op instead of
   an O(leaves) Python loop.
@@ -29,13 +30,12 @@ regardless of leaf count.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
+from repro.kernels import iter_fisher as _kernels
 from repro.kernels import ref as _ref
 from repro.kernels.iter_fisher import BLOCK  # default tile size for all kernels
 
@@ -105,7 +105,7 @@ def pack_spec(tree: Pytree, block: Optional[int] = None) -> PackSpec:
     block = _resolve_block(block)
     leaves, treedef = jax.tree.flatten(tree)
     shapes = tuple(tuple(leaf.shape) for leaf in leaves)
-    dtypes = tuple(str(jnp.asarray(leaf).dtype) for leaf in leaves)
+    dtypes = tuple(str(jnp.result_type(leaf)) for leaf in leaves)
     key = (treedef, shapes, dtypes, block)
     spec = _SPEC_CACHE.get(key)
     if spec is None:
@@ -167,14 +167,6 @@ def unpack(
 # ---------------------------------------------------------------------------
 
 
-def _compensate_kernel(lam_ref, g_ref, d_ref, o_ref, *, tau: int):
-    g = g_ref[...]
-    lam = lam_ref[0]
-    for i in range(tau):
-        g = g + lam * g * g * d_ref[i, :]
-    o_ref[...] = g
-
-
 def compensate_packed(
     gflat: jax.Array,
     dflat: jax.Array,
@@ -184,37 +176,10 @@ def compensate_packed(
 ) -> jax.Array:
     """Eq. 9 over the packed buffer: one launch for the whole pytree."""
     global KERNEL_LAUNCHES
-    block = _resolve_block(block)
-    tau = dflat.shape[0]
-    if tau == 0:
+    if dflat.shape[0] == 0:
         return gflat
-    nb = gflat.shape[0] // block
     KERNEL_LAUNCHES += 1
-    return pl.pallas_call(
-        functools.partial(_compensate_kernel, tau=tau),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),  # λ broadcast to every tile
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((tau, block), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(gflat.shape, jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(lam).reshape(1).astype(jnp.float32), gflat, dflat)
-
-
-def _stats_kernel(g_ref, d_ref, vr_ref, va_ref, nvr_ref, nva_ref, s1_ref, s2_ref,
-                  *, alpha: float):
-    # Each grid step writes its own s1/s2 partial (race-free on any
-    # backend, sequential or parallel grid); the BLOCK→1 reduction happens
-    # here in the same data pass, the tiny nb→1 epilogue sum outside.
-    g, d, vr, va = g_ref[...], d_ref[...], vr_ref[...], va_ref[...]
-    dv_r = (1.0 - alpha) * (g - vr)
-    s1_ref[0] = jnp.sum(dv_r * va)
-    s2_ref[0] = jnp.sum(va * va)
-    nvr_ref[...] = alpha * vr + (1.0 - alpha) * g
-    nva_ref[...] = alpha * va + (1.0 - alpha) * (g * g * d)
+    return _kernels.compensate_call(gflat, dflat, lam, _resolve_block(block), interpret)
 
 
 def stats_packed(
@@ -229,28 +194,10 @@ def stats_packed(
     """Alg. 1 λ-statistics over the packed buffer: one launch, s1/s2
     block-reduced on-device in the same pass. Returns (v_r', v_a', s1, s2)."""
     global KERNEL_LAUNCHES
-    block = _resolve_block(block)
-    nb = gflat.shape[0] // block
     KERNEL_LAUNCHES += 1
-    nvr, nva, s1b, s2b = pl.pallas_call(
-        functools.partial(_stats_kernel, alpha=alpha),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)) for _ in range(4)],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(gflat.shape, jnp.float32),
-            jax.ShapeDtypeStruct(gflat.shape, jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(gflat, dflat, vrflat, vaflat)
-    return nvr, nva, jnp.sum(s1b), jnp.sum(s2b)
+    return _kernels.stats_call(
+        gflat, dflat, vrflat, vaflat, alpha, _resolve_block(block), interpret
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,6 @@ from typing import Dict, Optional
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import cost_analysis_dict
 from repro.configs.common import SHAPES, InputShape, input_specs, shape_applicable
 from repro.launch import shardings as sh
 from repro.launch.hlo_analysis import (
@@ -126,7 +125,7 @@ def _lower_compile(cfg, shape, mesh, remat: bool, microbatch: int, variant: str 
     batch_s = input_specs(cfg, shape)
     b_shard = sh.named(mesh, sh.batch_pspecs(cfg, shape, maxes, dp, model_ax))
 
-    with mesh, shard_hints.use_hints(_hints(dp, model_ax)):
+    with jax.set_mesh(mesh), shard_hints.use_hints(_hints(dp, model_ax)):
         if shape.kind == "train":
             opt = adamw(lr=1e-3)
             opt_s = jax.eval_shape(opt.init, params_s)
@@ -171,7 +170,7 @@ def _cost_point(cfg, shape, mesh, remat, num_layers, variant="baseline"):
         compiled = _lower_compile(small, shape, mesh, remat, microbatch=1, variant=variant)
     finally:
         scan_util.UNROLL = False
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     text = compiled.as_text()
     colls = parse_collectives(text, default_group=mesh.devices.size)
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0)), colls

@@ -6,8 +6,8 @@ and sum wire bytes of every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute, with ring-algorithm multipliers and
 replica-group sizes taken from the instruction attributes.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (see repro.core.profiler).
+Hardware constants come from ``repro.core.chips`` for the mesh's device
+kind (TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link ICI).
 """
 
 from __future__ import annotations
@@ -16,8 +16,7 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from repro.compat import cost_analysis_dict
-from repro.core.profiler import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.core.chips import TPU_V5E, ChipSpec, chip_spec
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -116,18 +115,19 @@ class RooflineTerms:
     bytes_per_device: float
     collective_bytes_per_device: float
     chips: int
+    chip: ChipSpec = TPU_V5E
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / self.chip.peak_flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.chip.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.chip.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -199,7 +199,7 @@ def analytic_memory_bytes(
 
 def roofline_from_compiled(compiled, mesh, hlo_text: Optional[str] = None) -> RooflineTerms:
     chips = mesh.devices.size
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
@@ -209,4 +209,5 @@ def roofline_from_compiled(compiled, mesh, hlo_text: Optional[str] = None) -> Ro
         bytes_per_device=byts,
         collective_bytes_per_device=colls.total_bytes,
         chips=chips,
+        chip=chip_spec(mesh.devices.flat[0]),
     ), colls

@@ -65,6 +65,10 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.models.config import ModelConfig
     from repro.models.registry import get_config
     from repro.ocl.streams import StreamConfig, make_stream

@@ -28,6 +28,7 @@ import numpy as np
 from repro.api import FerretSession
 from repro.core.compensation import CompensationConfig
 from repro.data.pipeline import DataPipeline, PipelineCfg, TokenStreamSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 from repro.models.registry import get_config
@@ -255,6 +256,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "ferret":
         run_ferret(args)
     else:

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import tree_flatten_with_path
 from repro.models import ssm as ssm_lib
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -103,7 +102,7 @@ def _init_leaf(key, path: str, shape, dtype):
 def init_params(cfg: ModelConfig, rng: jax.Array) -> Dict:
     shapes = param_shapes(cfg)
     dtype = jnp.dtype(cfg.param_dtype)
-    flat, treedef = tree_flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
+    flat, treedef = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
     leaves = []
     for i, (path, shape) in enumerate(flat):
         pathstr = "/".join(str(p.key) for p in path)
@@ -176,7 +175,7 @@ def param_pspecs(
         return P()
 
     shapes = param_shapes(cfg)
-    flat, treedef = tree_flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
+    flat, treedef = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
     specs = []
     for path, shape in flat:
         pathstr = "/".join(str(p.key) for p in path)

@@ -26,7 +26,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.config import ModelConfig
 
 DEFAULT_WARMUP = 2
@@ -59,7 +58,7 @@ def time_jit(
     compiled = jax.jit(fn).lower(*args).compile()
     compile_s = time.perf_counter() - t0
     try:
-        cost = compat.cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
     except Exception:
         cost = {}
     for _ in range(max(warmup, 1)):

@@ -16,10 +16,9 @@ import dataclasses
 from typing import Optional
 
 from repro.core import planner as planner_lib
+from repro.core.chips import chip_spec
 from repro.core.profiler import ModelProfile, profile_for
 from repro.models.config import ModelConfig
-
-HBM_PER_CHIP = 16 * 2**30  # TPU v5e
 
 
 def _is_topology(cluster) -> bool:
@@ -48,7 +47,7 @@ class DeviceLossError(RuntimeError):
 @dataclasses.dataclass
 class ClusterSpec:
     chips: int
-    hbm_per_chip: int = HBM_PER_CHIP
+    hbm_per_chip: int = dataclasses.field(default_factory=lambda: chip_spec().hbm_bytes)
 
     @property
     def total_hbm(self) -> int:

@@ -35,10 +35,12 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-# Per-device memory fallback when the backend reports none (CPU fake
-# devices, older runtimes): TPU v5e HBM. Override with
+from repro.core.chips import TPU_V5E
+
+# Per-device memory of a non-TPU device, which reports none (CPU fake
+# devices): the v5e row of repro.core.chips. Override with
 # REPRO_DEVICE_MEM_BYTES or the memory_per_device= argument.
-DEFAULT_MEMORY_PER_DEVICE = 16 * 2**30
+DEFAULT_MEMORY_PER_DEVICE = TPU_V5E.hbm_bytes
 
 # Fraction of per-device memory handed to the planner (headroom for XLA
 # scratch, collectives buffers, host transfers) — matches the historical
@@ -52,13 +54,15 @@ def _device_memory(device, override: Optional[int]) -> int:
     env = os.environ.get("REPRO_DEVICE_MEM_BYTES", "").strip()
     if env:
         return int(env)
-    try:
-        stats = device.memory_stats()
-    except Exception:
-        stats = None
-    if stats and stats.get("bytes_limit"):
-        return int(stats["bytes_limit"])
-    return DEFAULT_MEMORY_PER_DEVICE
+    if device.platform != "tpu":
+        return DEFAULT_MEMORY_PER_DEVICE
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory_stats()['bytes_limit']; "
+            "pass memory_per_device= or set REPRO_DEVICE_MEM_BYTES"
+        )
+    return int(limit)
 
 
 @dataclasses.dataclass(frozen=True)

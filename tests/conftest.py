@@ -2,9 +2,7 @@
 multi-device sharding tests spawn subprocesses that set the flag first."""
 
 import dataclasses
-import importlib.util
 import os
-import sys
 import tempfile
 
 import jax
@@ -18,16 +16,6 @@ import pytest
 os.environ.setdefault(
     "REPRO_PROFILE_DIR", tempfile.mkdtemp(prefix="repro-test-profile-")
 )
-
-# The container has no network access: if the real hypothesis isn't
-# installed, register the deterministic fallback before test collection so
-# the property-based modules still collect and run (see _hypothesis_fallback).
-# conftest executes fully before any test module imports hypothesis, so
-# registering after the imports above is safe.
-if importlib.util.find_spec("hypothesis") is None:
-    import _hypothesis_fallback as _hyp_stub
-
-    sys.modules["hypothesis"] = _hyp_stub
 
 
 @pytest.fixture(scope="session")

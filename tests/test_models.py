@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from conftest import smoke_cfg
-from repro.compat import tree_flatten_with_path
 from repro.models import transformer as T
 from repro.models.registry import ARCHITECTURES, get_config
 
@@ -87,9 +86,9 @@ def test_param_specs_match_shapes(arch):
     shapes = T.param_shapes(cfg)
     mesh_axes = {"pod": 2, "data": 16, "model": 16}
     specs = T.param_pspecs(cfg, mesh_axes, data_axes=("pod", "data"))
-    flat_shapes = tree_flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))[0]
+    flat_shapes = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))[0]
     sh_map = {tuple(p): v for p, v in flat_shapes}
-    sp_flat = tree_flatten_with_path(
+    sp_flat = jax.tree.flatten_with_path(
         specs, is_leaf=lambda s: s.__class__.__name__ == "PartitionSpec"
     )[0]
     assert len(sh_map) == len(sp_flat)

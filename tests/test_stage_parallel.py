@@ -26,12 +26,12 @@ CODE = textwrap.dedent(
     M, b, s = 3, 2, 8
     toks = jax.random.randint(jax.random.PRNGKey(1), (M, b, s + 1), 0, cfg.vocab_size)
     batch = {"tokens": toks[:, :, :-1], "labels": toks[:, :, 1:]}
-    with mesh:
+    with jax.set_mesh(mesh):
         logits = spatial_pipeline_logits(cfg, params, batch, mesh, num_stages=4)
     for m in range(M):
         ref, _ = T.forward(cfg, params, {"tokens": batch["tokens"][m]})
         np.testing.assert_allclose(np.asarray(logits[m]), np.asarray(ref), rtol=2e-4, atol=2e-4)
-    with mesh:
+    with jax.set_mesh(mesh):
         g_sp = jax.grad(lambda p: spatial_pipeline_loss(cfg, p, batch, mesh, 4))(params)
     def plain_loss(p):
         tot = 0.0

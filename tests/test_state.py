@@ -143,7 +143,7 @@ def test_engine_state_is_keyed_pytree():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(
     mask_a=st.integers(0, 2 ** (L - 1) - 1),
     mask_b=st.integers(0, 2 ** (L - 1) - 1),
@@ -160,7 +160,7 @@ def test_merge_resplit_identity(mask_a, mask_b):
     assert _tree_equal(remap_stage_params(cfg, sp_b, bounds_a), sp_a)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(
     mask_b=st.integers(0, 2 ** (L - 1) - 1),
     num_slots=st.integers(1, 4),

@@ -103,7 +103,7 @@ SUBPROCESS_SHARDING = textwrap.dedent(
     p_sh = sh.named(mesh, pspecs)
     batch_s = input_specs(cfg, shape)
     b_sh = sh.named(mesh, sh.batch_pspecs(cfg, shape, maxes, ("data",), "model"))
-    with mesh:
+    with jax.set_mesh(mesh):
         if "{kind}" == "train":
             opt = adamw(1e-3)
             opt_s = jax.eval_shape(opt.init, params)
@@ -118,8 +118,7 @@ SUBPROCESS_SHARDING = textwrap.dedent(
             step = make_decode_step(cfg)
             c = jax.jit(step, in_shardings=(p_sh, c_sh, b_sh)).lower(
                 jax.eval_shape(lambda: params), cache_s, batch_s).compile()
-    from repro.compat import cost_analysis_dict
-    print(json.dumps({{"ok": True, "flops": cost_analysis_dict(c).get("flops", 0)}}))
+    print(json.dumps({{"ok": True, "flops": c.cost_analysis().get("flops", 0)}}))
     """
 )
 
@@ -165,3 +164,28 @@ def test_train_driver_plain_mode_smoke(tmp_path):
     p2 = subprocess.run(cmd, capture_output=True, text=True, timeout=600, cwd=root, env=env)
     assert p2.returncode == 0, p2.stderr[-2000:]
     assert "restored from checkpoint" in p2.stdout
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_is_placed_from_outside(env_dir, tmp_path, monkeypatch):
+    """Entry points keep JAX's cache where JAX_COMPILATION_CACHE_DIR says,
+    setting nothing in code; unset, at the checkout's fixed .jax_cache/."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            want = Path(__file__).resolve().parents[1] / ".jax_cache"
+            assert got == str(want) == jax.config.jax_compilation_cache_dir
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

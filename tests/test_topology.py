@@ -76,6 +76,48 @@ def test_discover_reads_the_jax_world():
         DeviceTopology.discover(model_axis=n + 1)
 
 
+@dataclasses.dataclass
+class _FakeDevice:
+    platform: str
+    device_kind: str
+    stats: dict = dataclasses.field(default_factory=dict)
+
+    def memory_stats(self):
+        return self.stats
+
+
+@pytest.mark.parametrize("device, want", [
+    (_FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": 123 * 2**20}), 123 * 2**20),
+    (_FakeDevice("tpu", "TPU v5 lite"), RuntimeError),
+    (_FakeDevice("cpu", "cpu"), 16 * 2**30),
+])
+def test_device_memory_is_reported_never_guessed_on_tpu(device, want, monkeypatch):
+    from repro.runtime.topology import _device_memory
+
+    monkeypatch.delenv("REPRO_DEVICE_MEM_BYTES", raising=False)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            _device_memory(device, None)
+    else:
+        assert _device_memory(device, None) == want
+    assert _device_memory(device, 7) == 7
+
+
+@pytest.mark.parametrize("device, want", [
+    (_FakeDevice("tpu", "TPU v5 lite"), "TPU v5 lite"),
+    (_FakeDevice("cpu", "cpu"), "TPU v5 lite"),
+    (_FakeDevice("tpu", "TPU v9 imaginary"), ValueError),
+])
+def test_chip_spec_is_keyed_by_device_kind(device, want):
+    from repro.core.chips import chip_spec
+
+    if want is ValueError:
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            chip_spec(device)
+    else:
+        assert chip_spec(device).device_kind == want
+
+
 def test_shrink_keeps_model_axis_only_when_divisible():
     t = DeviceTopology(device_count=8, mesh_shape=(4, 2))
     assert t.shrink(2).mesh_shape == (3, 2)  # 6 % 2 == 0: model axis survives
