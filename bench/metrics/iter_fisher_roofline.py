@@ -1,0 +1,14 @@
+"""The Iter-Fisher kernels' share of their roofline, in percent: the least
+time the bytes they need take at the chip's HBM bandwidth (they do about
+one FLOP per byte, so bandwidth bounds them), over their device time."""
+
+import costs
+
+
+def read(run):
+    t = run.trace
+    if not t or t["kernel_s"] <= 0:
+        return None
+    m = run.cell.config["model"]
+    need = costs.iter_fisher_bytes_per_round(m, run.cell.stated["plan"]["bounds"]) * t["rounds"]
+    return 100.0 * need / run.peaks["hbm_bytes_per_s"] / t["kernel_s"]

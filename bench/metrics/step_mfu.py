@@ -1,0 +1,17 @@
+"""Model FLOP utilization of the whole step, in percent: the forward and
+backward FLOPs of every round the plan trained in the window (new and
+replayed rows; rounds a plan skips count nothing), over the window and the
+chips' bf16 peak."""
+
+import costs
+
+
+def read(run):
+    trained = sum(s.trained_rounds for s in run.window_segments)
+    if run.window_s <= 0 or trained <= 0:
+        return None
+    tr = run.cell.traffic
+    rows = tr["batch"] + tr["replay_rows"]
+    flops = costs.decoder_train_flops(run.cell.config["model"], rows, tr["seq"])
+    achieved = flops * trained / run.window_s
+    return 100.0 * achieved / (run.chips * run.peaks["bf16_flops_per_s"])
