@@ -35,6 +35,7 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Union
 import numpy as np
 
 from repro import faults as faults_lib
+from repro.core.spans import span
 from repro.faults import FeederDeathError, TransientFaultError
 from repro.ocl.streams import StreamConfig, make_stream
 
@@ -291,7 +292,8 @@ class BufferedStreamSource(StreamSource):
             self._exhausted = True
             return
         if self.transform is not None:
-            chunk = self.transform(chunk)
+            with span("ferret.feeder.prepare"):
+                chunk = self.transform(chunk)
         self._pending.append(chunk)
         self._note_peak()
 
@@ -314,49 +316,49 @@ class BufferedStreamSource(StreamSource):
 
     def _prefetch_take(self, n: int) -> Optional[Batch]:
         """The background worker's take, with the feeder-death point."""
-        spec = faults_lib.fire("stream.prefetch", n=n)
-        if spec is not None and spec.kind == "feeder_death":
-            raise FeederDeathError("injected prefetch feeder death")
-        return self._inner_take(n)
+        with span("ferret.feeder.prepare"):
+            spec = faults_lib.fire("stream.prefetch", n=n)
+            if spec is not None and spec.kind == "feeder_death":
+                raise FeederDeathError("injected prefetch feeder death")
+            return self._inner_take(n)
 
     def _sync(self) -> None:
         if self._future is not None:
             (fut, n), self._future = self._future, None
-            t0 = time.perf_counter()
-            try:
-                got = fut.result()
-            except FeederDeathError:
-                # the feeder thread died before touching the source: fall
-                # back to a synchronous pull of the same request —
-                # exactly-once holds because the failed take consumed
-                # nothing
-                self.take_wait_s += time.perf_counter() - t0
+            failed = None
+            with span("ferret.feeder.wait") as wait:
+                try:
+                    got = fut.result()
+                except FeederDeathError:
+                    # the feeder thread died before touching the source:
+                    # exactly-once holds because the failed take consumed
+                    # nothing
+                    failed = "stream.prefetch"
+                except TransientFaultError:
+                    # the worker's *take* failed (transient,
+                    # pre-consumption): the outstanding fault is at the
+                    # take point, not the prefetch point
+                    failed = "stream.take"
+            self.take_wait_s += wait.seconds
+            if failed is not None:
+                # fall back to a synchronous pull of the same request
                 self._pull(n)
-                faults_lib.resolved("stream.prefetch")
+                faults_lib.resolved(failed)
                 return
-            except TransientFaultError:
-                # the worker's *take* failed (transient, pre-consumption):
-                # same synchronous fallback, but the outstanding fault is
-                # at the take point, not the prefetch point
-                self.take_wait_s += time.perf_counter() - t0
-                self._pull(n)
-                faults_lib.resolved("stream.take")
-                return
-            self.take_wait_s += time.perf_counter() - t0
             self._admit(got)
 
     def _pull(self, n: int) -> None:
         if self._exhausted:
             return
-        t0 = time.perf_counter()
-        try:
-            got = self._inner_take(n)
-        except TransientFaultError:
-            # transient by contract (raised before any consumption):
-            # one immediate retry
-            got = self._inner_take(n)
-            faults_lib.resolved("stream.take")
-        self.take_wait_s += time.perf_counter() - t0
+        with span("ferret.feeder.wait") as wait:
+            try:
+                got = self._inner_take(n)
+            except TransientFaultError:
+                # transient by contract (raised before any consumption):
+                # one immediate retry
+                got = self._inner_take(n)
+                faults_lib.resolved("stream.take")
+        self.take_wait_s += wait.seconds
         self._admit(got)
 
     # -- prefetch ----------------------------------------------------------

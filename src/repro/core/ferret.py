@@ -28,9 +28,9 @@ import dataclasses
 import math
 import os
 import threading
-import time
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -39,6 +39,7 @@ from repro.core import planner as planner_lib
 from repro.core import schedule as sched_lib
 from repro.core.pipeline import FerretEngine, staged_from_transformer
 from repro.core.profiler import ModelProfile, profile_for
+from repro.core.spans import span
 from repro.models.config import ModelConfig
 from repro.ocl.algorithms import OCLConfig
 from repro.ocl.registry import OCLAlgorithm, PrepareContext, get_algorithm
@@ -425,99 +426,111 @@ class FerretTrainer:
         lam_all: list = []
         try:
             while R is None or cursor < R:
-                want = seg if R is None else min(seg, R - cursor)
-                rows = feeder.take(want)
-                if rows is None:
-                    break  # source exhausted
-                seg_len = next(iter(rows.values())).shape[0]
-                seg_end = cursor + seg_len
-                if seg_len < want:
-                    R = seg_end  # source ended early: true stream end found
-                # one causal build; segments slice it. A bounded stream
-                # builds straight to its end; an unknown end grows
-                # geometrically — construction is causal, so a longer
-                # rebuild is bit-identical on its prefix (the same
-                # continuation ``build_schedule(warmup=)`` computes), and
-                # doubling keeps host-side schedule work O(R) per run.
-                if full_sched is None or full_sched.num_rounds < seg_end:
-                    if R is not None:
-                        build_len = max(R, seg_end)
-                    else:
-                        built = 0 if full_sched is None else full_sched.num_rounds
-                        build_len = max(seg_end, 2 * built, 2 * seg)
-                    full_sched = sched_lib.build_schedule(
-                        self.plan.config, P, build_len
-                    )
-                # pad every slice to the segment length with inert rounds
-                # (identity on engine state): one compiled scan serves the
-                # whole run, ragged tail included
-                engine_sched = sched_lib.pad_schedule(
-                    sched_lib.slice_schedule(full_sched, cursor, seg_end), seg
-                )
-                if engine is None:
-                    engine = FerretEngine(
-                        self.staged, engine_sched, self.optimizer,
-                        self.cfg.compensation, lr=self.cfg.lr,
-                        penalty_fn=penalty_fn, mesh=self.mesh,
-                        hints=self.shard_hints,
-                    )
-                else:
-                    engine.set_schedule(engine_sched)
-                state = engine.init_state(
-                    stages, opt_states, comp_states, rings=rings, deltas=deltas,
-                    bounds=self.boundaries, sched_origin=0,
-                )
-                # only this segment's rounds ever reach the device
-                seg_stream = {k: jnp.asarray(v) for k, v in rows.items()}
-                if seg > seg_len:
-                    # padding rounds repeat the last item (never admitted)
-                    seg_stream = {
-                        k: jnp.concatenate(
-                            [v, jnp.repeat(v[-1:], seg - seg_len, axis=0)]
+                with span("ferret.segment", step=seg_index):
+                    want = seg if R is None else min(seg, R - cursor)
+                    with span("ferret.take"):
+                        rows = feeder.take(want)
+                    if rows is None:
+                        break  # source exhausted
+                    seg_len = next(iter(rows.values())).shape[0]
+                    seg_end = cursor + seg_len
+                    if seg_len < want:
+                        R = seg_end  # source ended early: true stream end found
+                    with span("ferret.schedule"):
+                        # one causal build; segments slice it. A bounded
+                        # stream builds straight to its end; an unknown end
+                        # grows geometrically — construction is causal, so
+                        # a longer rebuild is bit-identical on its prefix
+                        # (the same continuation ``build_schedule(warmup=)``
+                        # computes), and doubling keeps host-side schedule
+                        # work O(R) per run.
+                        if full_sched is None or full_sched.num_rounds < seg_end:
+                            if R is not None:
+                                build_len = max(R, seg_end)
+                            else:
+                                built = 0 if full_sched is None else full_sched.num_rounds
+                                build_len = max(seg_end, 2 * built, 2 * seg)
+                            full_sched = sched_lib.build_schedule(
+                                self.plan.config, P, build_len
+                            )
+                        # pad every slice to the segment length with inert
+                        # rounds (identity on engine state): one compiled
+                        # scan serves the whole run, ragged tail included
+                        engine_sched = sched_lib.pad_schedule(
+                            sched_lib.slice_schedule(full_sched, cursor, seg_end), seg
                         )
-                        for k, v in seg_stream.items()
-                    }
-                # overlap: pull segment k+1 on the host while k computes
-                if R is None or seg_end < R:
-                    feeder.prefetch(seg if R is None else min(seg, R - seg_end))
-                if penalty_fn is not None and penalty is None:
-                    # single-plan run: the anchor never refreshes after the
-                    # first chunk sets it, so split Ω/θ* once and reuse the
-                    # same pytree every segment (stable jit arguments, no
-                    # per-segment re-split/re-upload of 2× model size)
-                    penalty = split_penalty_extras(
-                        self.algorithm, self.model_cfg, self.boundaries
-                    )
-                t0 = time.perf_counter()
-                final_state, ys = engine.run(state, seg_stream, penalty)
-                seg_wall = time.perf_counter() - t0
-                feeder.ack()  # segment complete: retained rows consumed
-                if self.cfg.profile_feedback and seg_index > 0 and seg_len > 0:
-                    # skip segment 0: its wall-clock includes the compile.
-                    # The single-plan run never replans, so the refinement
-                    # lands in the store for future runs/replans.
-                    from repro.profile.bridge import observe_segment
+                        if engine is None:
+                            engine = FerretEngine(
+                                self.staged, engine_sched, self.optimizer,
+                                self.cfg.compensation, lr=self.cfg.lr,
+                                penalty_fn=penalty_fn, mesh=self.mesh,
+                                hints=self.shard_hints,
+                            )
+                        else:
+                            engine.set_schedule(engine_sched)
+                        state = engine.init_state(
+                            stages, opt_states, comp_states, rings=rings, deltas=deltas,
+                            bounds=self.boundaries, sched_origin=0,
+                        )
+                    with span("ferret.upload"):
+                        # only this segment's rounds ever reach the device
+                        seg_stream = {k: jnp.asarray(v) for k, v in rows.items()}
+                        if seg > seg_len:
+                            # padding rounds repeat the last item (never admitted)
+                            seg_stream = {
+                                k: jnp.concatenate(
+                                    [v, jnp.repeat(v[-1:], seg - seg_len, axis=0)]
+                                )
+                                for k, v in seg_stream.items()
+                            }
+                    # overlap: pull segment k+1 on the host while k computes
+                    if R is None or seg_end < R:
+                        feeder.prefetch(seg if R is None else min(seg, R - seg_end))
+                    if penalty_fn is not None and penalty is None:
+                        # single-plan run: the anchor never refreshes after
+                        # the first chunk sets it, so split Ω/θ* once and
+                        # reuse the same pytree every segment (stable jit
+                        # arguments, no per-segment re-split/re-upload of
+                        # 2× model size)
+                        penalty = split_penalty_extras(
+                            self.algorithm, self.model_cfg, self.boundaries
+                        )
+                    with span("ferret.dispatch") as dispatch:
+                        final_state, ys = engine.run(state, seg_stream, penalty)
+                    with span("ferret.fetch") as fetch:
+                        ys = jax.device_get(ys)
+                    # dispatch to the results on the host: the segment's
+                    # device time, which the dispatch alone returns before
+                    seg_wall = fetch.end - dispatch.start
+                    feeder.ack()  # segment complete: retained rows consumed
+                    if self.cfg.profile_feedback and seg_index > 0 and seg_len > 0:
+                        # skip segment 0: its wall-clock includes the
+                        # compile. The single-plan run never replans, so the
+                        # refinement lands in the store for future
+                        # runs/replans.
+                        from repro.profile.bridge import observe_segment
 
-                    # the compiled scan executes `seg` rounds (inert padding
-                    # included), so that is the wall-clock's denominator
-                    refined = observe_segment(
-                        self.model_cfg, self.batch, self.seq,
-                        self.profile, self.plan, seg, seg_wall,
-                    )
-                    if refined is not None:
-                        self.profile = refined[0]
-                seg_index += 1
-                ys = {k: v[:seg_len] for k, v in ys.items()}  # drop padding
-                stages = list(final_state.stage_params)
-                rings = tuple(final_state.rings)
-                deltas = tuple(final_state.deltas)
-                opt_states = tuple(final_state.opt_states)
-                comp_states = tuple(final_state.comp_states)
-                acc_all.append(np.asarray(ys["acc"], dtype=np.float64))
-                loss_all.append(np.asarray(ys["loss"]))
-                adm_all.append(np.asarray(ys["admitted"], dtype=np.float64))
-                lam_all.append(np.asarray(ys["lam"]))
-                cursor = seg_end
+                        # the compiled scan executes `seg` rounds (inert
+                        # padding included), so that is the wall-clock's
+                        # denominator
+                        refined = observe_segment(
+                            self.model_cfg, self.batch, self.seq,
+                            self.profile, self.plan, seg, seg_wall,
+                        )
+                        if refined is not None:
+                            self.profile = refined[0]
+                    seg_index += 1
+                    ys = {k: v[:seg_len] for k, v in ys.items()}  # drop padding
+                    stages = list(final_state.stage_params)
+                    rings = tuple(final_state.rings)
+                    deltas = tuple(final_state.deltas)
+                    opt_states = tuple(final_state.opt_states)
+                    comp_states = tuple(final_state.comp_states)
+                    acc_all.append(np.asarray(ys["acc"], dtype=np.float64))
+                    loss_all.append(ys["loss"])
+                    adm_all.append(np.asarray(ys["admitted"], dtype=np.float64))
+                    lam_all.append(ys["lam"])
+                    cursor = seg_end
         finally:
             feeder.close()
 

@@ -253,13 +253,17 @@ class FerretEngine:
         K = self.sched.delta_ring
         f32 = jnp.float32
 
+        # the ``ferret.*`` scopes name each layer of the round in the ops'
+        # metadata (the profiler's ``tf_op`` path); they change nothing else
         def full_loss(stages_t):
-            x = None
-            for j in range(P):
-                x = self.staged.forward_stage(j, stages_t[j], x, batch)
-            loss, metrics = self.staged.loss(x, batch)
+            with jax.named_scope("ferret.forward"):
+                x = None
+                for j in range(P):
+                    x = self.staged.forward_stage(j, stages_t[j], x, batch)
+                loss, metrics = self.staged.loss(x, batch)
             if self.penalty_fn is not None:
-                loss = loss + self.penalty_fn(stages_t, penalty)
+                with jax.named_scope("ferret.penalty"):
+                    loss = loss + self.penalty_fn(stages_t, penalty)
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(full_loss, has_aux=True)(stages)
@@ -268,40 +272,47 @@ class FerretEngine:
         new_stages, new_rings, new_deltas, new_opts, new_comps = [], [], [], [], []
         lam_sum = jnp.zeros((), f32)
         for j in range(P):
-            bmask = pmask * xs["backward"][j].astype(f32)
-            g_j = jax.tree.map(lambda g: g.astype(f32) * bmask, grads[j])
-
             # ---- push (accumulate into the gradient ring, T2) ----
-            slot = jnp.maximum(xs["push_slot"][j], 0)
+            with jax.named_scope("ferret.push"):
+                bmask = pmask * xs["backward"][j].astype(f32)
+                g_j = jax.tree.map(lambda g: g.astype(f32) * bmask, grads[j])
+                slot = jnp.maximum(xs["push_slot"][j], 0)
 
-            def do_push(ring, g_j=g_j, slot=slot, reset=xs["push_reset"][j]):
-                cur = _dyn_index(ring, slot)
-                base = jax.tree.map(lambda c, g: jnp.where(reset, g, c + g), cur, g_j)
-                return _dyn_update(ring, base, slot)
+                def do_push(ring, g_j=g_j, slot=slot, reset=xs["push_reset"][j]):
+                    cur = _dyn_index(ring, slot)
+                    base = jax.tree.map(lambda c, g: jnp.where(reset, g, c + g), cur, g_j)
+                    return _dyn_update(ring, base, slot)
 
-            ring_j = jax.lax.cond(xs["push_slot"][j] >= 0, do_push, lambda r: r, rings[j])
+                ring_j = jax.lax.cond(
+                    xs["push_slot"][j] >= 0, do_push, lambda r: r, rings[j]
+                )
 
             # ---- pop (compensate + apply, Alg. 1) ----
             def do_pop(args, j=j):
                 params, opt_s, comp_s, ring, dring = args
-                pslot = jnp.maximum(xs["pop_slot"][j], 0)
-                g = jax.tree.map(
-                    lambda a: a * xs["pop_scale"][j], _dyn_index(ring, pslot)
-                )
-                order = (xs["delta_push"][j] + jnp.arange(K)) % K  # oldest→newest
-                mask = xs["delta_mask"][j]
-                dl = jax.tree.map(
-                    lambda a: a[order] * mask.reshape((K,) + (1,) * (a.ndim - 1)), dring
-                )
-                comp_s, gc = comp_lib.compensate(
-                    self.comp_cfg, comp_s, g, dl, lr=self.lr, tau=xs["tau"][j]
-                )
-                newp, new_opt = self.opt.update(params, gc, opt_s)
-                dnew = jax.tree.map(
-                    lambda a, b: a.astype(f32) - b.astype(f32), newp, params
-                )
-                dslot = jnp.maximum(xs["delta_push"][j], 0)
-                dring = _dyn_update(dring, dnew, dslot)
+                with jax.named_scope("ferret.delta_gather"):
+                    pslot = jnp.maximum(xs["pop_slot"][j], 0)
+                    g = jax.tree.map(
+                        lambda a: a * xs["pop_scale"][j], _dyn_index(ring, pslot)
+                    )
+                    order = (xs["delta_push"][j] + jnp.arange(K)) % K  # oldest→newest
+                    mask = xs["delta_mask"][j]
+                    dl = jax.tree.map(
+                        lambda a: a[order] * mask.reshape((K,) + (1,) * (a.ndim - 1)),
+                        dring,
+                    )
+                with jax.named_scope("ferret.compensate"):
+                    comp_s, gc = comp_lib.compensate(
+                        self.comp_cfg, comp_s, g, dl, lr=self.lr, tau=xs["tau"][j]
+                    )
+                with jax.named_scope("ferret.optimizer"):
+                    newp, new_opt = self.opt.update(params, gc, opt_s)
+                with jax.named_scope("ferret.delta_ring"):
+                    dnew = jax.tree.map(
+                        lambda a, b: a.astype(f32) - b.astype(f32), newp, params
+                    )
+                    dslot = jnp.maximum(xs["delta_push"][j], 0)
+                    dring = _dyn_update(dring, dnew, dslot)
                 return (newp, new_opt, comp_s, ring, dring)
 
             operands = (stages[j], opts[j], comps[j], ring_j, deltas[j])

@@ -78,6 +78,8 @@ def compensate_call(
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct(gf.shape, gf.dtype),
         interpret=interpret,
+        name="iter_fisher_compensate",
+        metadata={"kernel": "iter_fisher_compensate"},
     )
     return _per_device(call)(jnp.asarray(lam).reshape(1).astype(jnp.float32), gf, df)
 
@@ -159,6 +161,8 @@ def stats_call(
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="iter_fisher_stats",
+        metadata={"kernel": "iter_fisher_stats"},
     )
     nvr, nva, s1, s2 = _per_device(call)(gf, df, vrf, vaf)
     return nvr, nva, jnp.sum(s1), jnp.sum(s2)

@@ -74,7 +74,6 @@ import functools
 import json
 import math
 import os
-import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -114,6 +113,7 @@ from repro.core.ferret import (
 from repro.core.pipeline import FerretEngine, staged_from_transformer
 from repro.core.profiler import ModelProfile, profile_for
 from repro.core.schedule import RingGeometry
+from repro.core.spans import span
 from repro.models import shard_hints as shard_hints_lib
 from repro.models.config import ModelConfig
 from repro.ocl.registry import OCLAlgorithm, PrepareContext, get_algorithm
@@ -148,7 +148,7 @@ class SegmentReport:
     replanned: bool  # did this segment start with a re-plan + remap?
     replan_s: float  # host-side planner time (0.0 when not replanned)
     remap_s: float  # merge/re-split remap time (0.0 when not replanned)
-    run_s: float  # engine build + compile + scan wall time
+    run_s: float  # schedule build to the fetch of the results: engine build, compile, scan
     result: StreamResult
     cache_hit: bool = False  # compiled scan reused from the engine cache
     rounds_compiled: int = 0  # bucketed scan length this segment ran under
@@ -930,322 +930,335 @@ class ElasticStreamTrainer:
 
         try:
             while R is None or cursor < R:
-                # ---- budget for this segment: fault request beats the
-                # schedule. Events are consumed exactly once, so a
-                # fault-shrunk budget is not clobbered by re-reading an
-                # already-applied event.
-                target = budget
-                if budget_fn is not None:
-                    b = budget_fn(cursor)
-                    if b is not None:
-                        target = float(b)
-                while event_idx < len(events) and events[event_idx].round <= cursor:
-                    target = events[event_idx].budget_bytes
-                    event_idx += 1
-                if self._pending_budget is not None:
-                    target, self._pending_budget = self._pending_budget, None
-                replanned, replan_s, remap_s = False, 0.0, 0.0
-                seg_rounds_lost = 0
-                # A pending topology shrink forces the replan even when the
-                # budget number is unchanged (a pure data-parallel loss
-                # keeps the per-device bound but changes the mesh, the
-                # profile scaling, and the cache scope): the survivors'
-                # world replaces the lost one before planning.
-                if self._pending_topology is not None:
-                    topo, self._pending_topology = self._pending_topology, None
-                    self._set_topology(topo)
-                    do_replan = True
-                else:
-                    do_replan = target != budget
-                if do_replan:
-                    t0 = time.perf_counter()
-                    new_plan = self.plan_for(target)
-                    replan_s = time.perf_counter() - t0
-                    new_bounds = list(new_plan.partition.bounds)
-                    P_new = new_plan.partition.num_stages
-                    # the schedule depends only on (config, stage count,
-                    # phase) — when those survive the switch, the carried
-                    # rings stay valid slot-for-slot even across a bounds
-                    # change; otherwise the remapper flushes them
-                    same_sched = (
-                        prev_plan is not None
-                        and prev_plan.partition.num_stages == P_new
-                        and prev_plan.config == new_plan.config
-                    )
-                    t0 = time.perf_counter()
-                    if opt_states is None:
-                        if new_bounds != bounds:
-                            # no segment ran yet: only params exist to remap
-                            stage_params = state_remap.remap_stage_params(
-                                self.model_cfg, stage_params, new_bounds
-                            )
-                    elif new_bounds != bounds or not same_sched:
-                        old_sched = full_sched
-                        if old_sched is None and rings is not None:
-                            # resumed rings whose schedule was never built
-                            # this run (a replan before the first segment):
-                            # rebuild the causal prefix they were filled
-                            # under so the remapper can flush/account
-                            old_sched = sched_lib.build_schedule(
-                                plan.config, plan.partition.num_stages,
-                                max(cursor - sched_origin, 1),
-                                phase=sched_origin,
-                            )
-                        remapped, seg_rounds_lost = self._remapper.remap(
-                            EngineState(
-                                stage_params=tuple(stage_params),
-                                rings=rings,
-                                deltas=deltas,
-                                opt_states=tuple(opt_states),
-                                comp_states=tuple(comp_states),
-                                bounds=tuple(bounds),
-                                geometry=sched_lib.ring_geometry(
-                                    plan.config, plan.partition.num_stages
-                                ),
-                                sched_origin=sched_origin,
-                            ),
-                            new_bounds,
-                            new_geometry=sched_lib.ring_geometry(
-                                new_plan.config, P_new
-                            ),
-                            same_schedule=same_sched,
-                            old_schedule=old_sched,
-                            rounds_into_schedule=cursor - sched_origin,
-                            carry_rings=self.carry_rings,
-                        )
-                        stage_params = list(remapped.stage_params)
-                        opt_states = remapped.opt_states
-                        comp_states = remapped.comp_states
-                        rings = remapped.rings
-                        deltas = remapped.deltas
-                    remap_s = time.perf_counter() - t0
-                    budget, plan, bounds, replanned = target, new_plan, new_bounds, True
-                    self._current_budget = budget
-                    self._current_plan = plan
-                    # segment-boundary hook: the algorithm may refresh
-                    # segment-constant state (e.g. the LwF teacher) — the
-                    # physically buffered rounds in place, future rounds via
-                    # the refreshed preparation context.
-                    self._refresh_buffered(feeder, stage_params)
-
-                # ---- pull this segment's rounds (replayed rows first)
-                want = self._segment_end(cursor, R, events, segment_rounds) - cursor
-                t_take = time.perf_counter()
-                rows = feeder.take(want)
-                take_s = time.perf_counter() - t_take
-                if rows is None:
-                    break  # source exhausted
-                seg_len = next(iter(rows.values())).shape[0]
-                seg_end = cursor + seg_len
-                if seg_len < want:
-                    R = seg_end  # source ended early: true stream end found
-                fault_round = next(
-                    (r for r in pending_faults if cursor <= r < seg_end), None
-                )
-
-                t0 = time.perf_counter()
-                P = plan.partition.num_stages
-                same_struct = (
-                    prev_plan is not None
-                    and prev_plan.partition.num_stages == P
-                    and prev_plan.config == plan.config
-                )
-                if not same_struct:
-                    # The schedule restarts here (first segment, or a
-                    # stage-count/config change). Ring contents were
-                    # already handled by the remapper — flushed into the
-                    # weights, Δθ history re-timed — so only the schedule
-                    # coordinates reset.
-                    sched_origin = cursor
-                    full_sched = None
-                need = seg_end - sched_origin
-                if full_sched is None or full_sched.num_rounds < need:
-                    # one causal build per structure; segments slice it. A
-                    # bounded stream builds straight to its end; an unknown
-                    # end grows geometrically — construction is causal, so
-                    # a longer rebuild is bit-identical on its prefix (the
-                    # same continuation ``build_schedule(warmup=)``
-                    # computes), and doubling keeps total host-side
-                    # schedule work O(R) per structure.
-                    if R is not None:
-                        build_len = max(R - sched_origin, need)
+                with span("ferret.segment", step=len(segments)):
+                    # ---- budget for this segment: fault request beats the
+                    # schedule. Events are consumed exactly once, so a
+                    # fault-shrunk budget is not clobbered by re-reading an
+                    # already-applied event.
+                    target = budget
+                    if budget_fn is not None:
+                        b = budget_fn(cursor)
+                        if b is not None:
+                            target = float(b)
+                    while event_idx < len(events) and events[event_idx].round <= cursor:
+                        target = events[event_idx].budget_bytes
+                        event_idx += 1
+                    if self._pending_budget is not None:
+                        target, self._pending_budget = self._pending_budget, None
+                    replanned, replan_s, remap_s = False, 0.0, 0.0
+                    seg_rounds_lost = 0
+                    # A pending topology shrink forces the replan even when the
+                    # budget number is unchanged (a pure data-parallel loss
+                    # keeps the per-device bound but changes the mesh, the
+                    # profile scaling, and the cache scope): the survivors'
+                    # world replaces the lost one before planning.
+                    if self._pending_topology is not None:
+                        topo, self._pending_topology = self._pending_topology, None
+                        self._set_topology(topo)
+                        do_replan = True
                     else:
-                        built = 0 if full_sched is None else full_sched.num_rounds
-                        build_len = max(need, 2 * built, 64)
-                    full_sched = sched_lib.build_schedule(
-                        plan.config, P, build_len, phase=sched_origin
-                    )
-                bucket_rounds = self.engine_cache.bucket_len(seg_len)
-                engine_sched = sched_lib.pad_schedule(
-                    sched_lib.slice_schedule(
-                        full_sched, cursor - sched_origin, seg_end - sched_origin
-                    ),
-                    bucket_rounds,
-                )
-                struct_key = (self._cache_scope, tuple(bounds))
-                compile_key = struct_key + (
-                    engine_sched.ring_size, engine_sched.delta_ring, bucket_rounds,
-                    self.batch, self.seq, tuple(sorted(rows)),
-                )
-
-                def _factory(bounds=bounds, engine_sched=engine_sched):
-                    staged = self.algorithm.wrap_staged(
-                        staged_from_transformer(self.model_cfg, bounds)
-                    )
-                    return FerretEngine(
-                        staged, engine_sched, self.optimizer,
-                        self.cfg.compensation, lr=self.cfg.lr,
-                        penalty_fn=stage_penalty_fn(self.algorithm),
-                        mesh=self._mesh, hints=self._shard_hints,
-                    )
-
-                engine = self.engine_cache.engine_for(struct_key, _factory)
-                # exec_lock spans seen → set_schedule → run → record: a
-                # shared engine (multi-tenant, same geometry) never has its
-                # schedule swapped under an in-flight scan, and concurrent
-                # first-users cannot both count a miss for one compile
-                with engine.exec_lock:
-                    cache_hit = self.engine_cache.seen(compile_key)
-                    engine.set_schedule(engine_sched)
-                    state = engine.init_state(
-                        stage_params, opt_states, comp_states,
-                        rings=rings, deltas=deltas,
-                        bounds=bounds, sched_origin=sched_origin,
-                    )
-                    # only this segment's rounds ever reach the device:
-                    # stream residency stays O(segment), not O(R)
-                    seg_stream = {k: jnp.asarray(v) for k, v in rows.items()}
-                    if bucket_rounds > seg_len:
-                        # bucket padding: repeat the last item (inert
-                        # schedule rounds never admit it, so state/metrics
-                        # are untouched)
-                        seg_stream = {
-                            k: jnp.concatenate(
-                                [v, jnp.repeat(v[-1:], bucket_rounds - seg_len, axis=0)]
-                            )
-                            for k, v in seg_stream.items()
-                        }
-                    # overlap: pull segment k+1 on the host while k computes
-                    if R is None or seg_end < R:
-                        nxt = self._segment_end(seg_end, R, events, segment_rounds)
-                        feeder.prefetch(nxt - seg_end)
-                    # segment-constant penalty extras (MAS Ω/ref): re-read
-                    # at every boundary so a re-plan refresh is picked up;
-                    # rides the compiled scan as an argument, never a
-                    # retrace
-                    penalty = (
-                        self._split_penalty_cached(bounds)
-                        if engine.penalty_fn is not None else None
-                    )
-                    try:
-                        final_state, ys = self._execute_segment(
-                            engine, state, seg_stream, supervisor_cfg,
-                            fault_round, fault_budget_scale, plan, cursor, seg_end,
-                            budget, penalty, sched_origin=sched_origin,
+                        do_replan = target != budget
+                    if do_replan:
+                        with span("ferret.replan") as replan:
+                            new_plan = self.plan_for(target)
+                        replan_s = replan.seconds
+                        new_bounds = list(new_plan.partition.bounds)
+                        P_new = new_plan.partition.num_stages
+                        # the schedule depends only on (config, stage count,
+                        # phase) — when those survive the switch, the carried
+                        # rings stay valid slot-for-slot even across a bounds
+                        # change; otherwise the remapper flushes them
+                        same_sched = (
+                            prev_plan is not None
+                            and prev_plan.partition.num_stages == P_new
+                            and prev_plan.config == new_plan.config
                         )
-                        if faults_at_cursor:
-                            # a previously-faulted segment just completed:
-                            # close out its recovery latency
-                            faults_lib.resolved("engine.step")
-                        faults_at_cursor = 0
-                    except (DeviceLossError, TransientFaultError) as e:
-                        # Re-run this segment from the same cursor — state
-                        # is unchanged and the feeder re-serves the retained
-                        # rows, so the stream stays exactly-once. Injected
-                        # faults fire once; a genuine device loss may not
-                        # have gone through a Supervisor, so make sure a
-                        # shrink was requested, and bail out if shrinking
-                        # stops making progress. A transient error re-runs
-                        # at the *same* budget: lost capacity shrinks the
-                        # plan, a hiccup does not.
-                        feeder.rewind()
-                        if fault_round is not None:
-                            pending_faults.remove(fault_round)
-                        num_faults += 1
-                        faults_at_cursor += 1
-                        if (
-                            isinstance(e, DeviceLossError)
-                            and self._pending_budget is None
-                            and self._pending_topology is None
-                        ):
-                            self.fatal_handler(fault_budget_scale)(e)
-                        if faults_at_cursor > _MAX_FAULTS_PER_SEGMENT:
-                            raise
-                        continue
-                    feeder.ack()  # segment complete: retained rows consumed
-                    run_s = time.perf_counter() - t0
-                    # account the compile/hit only now: a faulted attempt
-                    # above never compiled, and must not poison the perf
-                    # counters
-                    self.engine_cache.record(compile_key, cache_hit)
+                        with span("ferret.remap") as remap:
+                            if opt_states is None:
+                                if new_bounds != bounds:
+                                    # no segment ran yet: only params exist to remap
+                                    stage_params = state_remap.remap_stage_params(
+                                        self.model_cfg, stage_params, new_bounds
+                                    )
+                            elif new_bounds != bounds or not same_sched:
+                                old_sched = full_sched
+                                if old_sched is None and rings is not None:
+                                    # resumed rings whose schedule was never built
+                                    # this run (a replan before the first segment):
+                                    # rebuild the causal prefix they were filled
+                                    # under so the remapper can flush/account
+                                    old_sched = sched_lib.build_schedule(
+                                        plan.config, plan.partition.num_stages,
+                                        max(cursor - sched_origin, 1),
+                                        phase=sched_origin,
+                                    )
+                                remapped, seg_rounds_lost = self._remapper.remap(
+                                    EngineState(
+                                        stage_params=tuple(stage_params),
+                                        rings=rings,
+                                        deltas=deltas,
+                                        opt_states=tuple(opt_states),
+                                        comp_states=tuple(comp_states),
+                                        bounds=tuple(bounds),
+                                        geometry=sched_lib.ring_geometry(
+                                            plan.config, plan.partition.num_stages
+                                        ),
+                                        sched_origin=sched_origin,
+                                    ),
+                                    new_bounds,
+                                    new_geometry=sched_lib.ring_geometry(
+                                        new_plan.config, P_new
+                                    ),
+                                    same_schedule=same_sched,
+                                    old_schedule=old_sched,
+                                    rounds_into_schedule=cursor - sched_origin,
+                                    carry_rings=self.carry_rings,
+                                )
+                                stage_params = list(remapped.stage_params)
+                                opt_states = remapped.opt_states
+                                comp_states = remapped.comp_states
+                                rings = remapped.rings
+                                deltas = remapped.deltas
+                        remap_s = remap.seconds
+                        budget, plan, bounds, replanned = target, new_plan, new_bounds, True
+                        self._current_budget = budget
+                        self._current_plan = plan
+                        # segment-boundary hook: the algorithm may refresh
+                        # segment-constant state (e.g. the LwF teacher) — the
+                        # physically buffered rounds in place, future rounds via
+                        # the refreshed preparation context.
+                        with span("ferret.refresh"):
+                            self._refresh_buffered(feeder, stage_params)
 
-                ys = {k: v[:seg_len] for k, v in ys.items()}  # drop bucket padding
-                stage_params = list(final_state.stage_params)
-                rings = tuple(final_state.rings)
-                deltas = tuple(final_state.deltas)
-                opt_states = tuple(final_state.opt_states)
-                comp_states = tuple(final_state.comp_states)
-                prev_plan = plan
-                if self.cfg.profile_feedback and cache_hit:
-                    # online refinement: fold observed wall-clock (cache-hit
-                    # segments only — a compile would swamp the signal) into
-                    # the profile + store; the *next* replan (BudgetEvent,
-                    # request_budget, on_fatal) plans from these numbers
-                    from repro.profile.bridge import observe_segment
-
-                    refined = observe_segment(
-                        self.model_cfg, self.batch, self.seq,
-                        self.profile, plan, bucket_rounds, run_s,
+                    # ---- pull this segment's rounds (replayed rows first)
+                    want = self._segment_end(cursor, R, events, segment_rounds) - cursor
+                    with span("ferret.take") as take:
+                        rows = feeder.take(want)
+                    take_s = take.seconds
+                    if rows is None:
+                        break  # source exhausted
+                    seg_len = next(iter(rows.values())).shape[0]
+                    seg_end = cursor + seg_len
+                    if seg_len < want:
+                        R = seg_end  # source ended early: true stream end found
+                    fault_round = next(
+                        (r for r in pending_faults if cursor <= r < seg_end), None
                     )
-                    if refined is not None:
-                        self.profile = refined[0]
-                        if self.cfg.t_d is None:
-                            self.t_d = planner_lib.default_data_interval(self.profile)
 
-                acc = np.asarray(ys["acc"], dtype=np.float64)
-                admitted = np.asarray(ys["admitted"], dtype=np.float64)
-                result = StreamResult(
-                    online_acc=float(acc.mean()),
-                    online_acc_curve=np.cumsum(acc) / np.arange(1, seg_len + 1),
-                    losses=np.asarray(ys["loss"]),
-                    admitted_frac=float(admitted.mean()),
-                    memory_bytes=plan.memory,
-                    planned_rate=plan.rate,
-                    empirical_rate=empirical_adaptation_rate(self.cfg, plan, admitted, seg_len),
-                    lam_curve=np.asarray(ys["lam"]),
-                    plan=plan,
-                )
-                segments.append(
-                    SegmentReport(
-                        start=cursor, end=seg_end, budget_bytes=budget,
-                        replanned=replanned, replan_s=replan_s, remap_s=remap_s,
-                        run_s=run_s, result=result,
-                        cache_hit=cache_hit, rounds_compiled=bucket_rounds,
-                        take_s=take_s, rounds_lost=seg_rounds_lost,
+                    # from here to the fetch of its results: the segment's
+                    # run time (run_s)
+                    with span("ferret.schedule") as schedule:
+                        P = plan.partition.num_stages
+                        same_struct = (
+                            prev_plan is not None
+                            and prev_plan.partition.num_stages == P
+                            and prev_plan.config == plan.config
+                        )
+                        if not same_struct:
+                            # The schedule restarts here (first segment, or a
+                            # stage-count/config change). Ring contents were
+                            # already handled by the remapper — flushed into the
+                            # weights, Δθ history re-timed — so only the schedule
+                            # coordinates reset.
+                            sched_origin = cursor
+                            full_sched = None
+                        need = seg_end - sched_origin
+                        if full_sched is None or full_sched.num_rounds < need:
+                            # one causal build per structure; segments slice it. A
+                            # bounded stream builds straight to its end; an unknown
+                            # end grows geometrically — construction is causal, so
+                            # a longer rebuild is bit-identical on its prefix (the
+                            # same continuation ``build_schedule(warmup=)``
+                            # computes), and doubling keeps total host-side
+                            # schedule work O(R) per structure.
+                            if R is not None:
+                                build_len = max(R - sched_origin, need)
+                            else:
+                                built = 0 if full_sched is None else full_sched.num_rounds
+                                build_len = max(need, 2 * built, 64)
+                            full_sched = sched_lib.build_schedule(
+                                plan.config, P, build_len, phase=sched_origin
+                            )
+                        bucket_rounds = self.engine_cache.bucket_len(seg_len)
+                        engine_sched = sched_lib.pad_schedule(
+                            sched_lib.slice_schedule(
+                                full_sched, cursor - sched_origin, seg_end - sched_origin
+                            ),
+                            bucket_rounds,
+                        )
+                        struct_key = (self._cache_scope, tuple(bounds))
+                        compile_key = struct_key + (
+                            engine_sched.ring_size, engine_sched.delta_ring, bucket_rounds,
+                            self.batch, self.seq, tuple(sorted(rows)),
+                        )
+
+                        def _factory(bounds=bounds, engine_sched=engine_sched):
+                            staged = self.algorithm.wrap_staged(
+                                staged_from_transformer(self.model_cfg, bounds)
+                            )
+                            return FerretEngine(
+                                staged, engine_sched, self.optimizer,
+                                self.cfg.compensation, lr=self.cfg.lr,
+                                penalty_fn=stage_penalty_fn(self.algorithm),
+                                mesh=self._mesh, hints=self._shard_hints,
+                            )
+
+                        engine = self.engine_cache.engine_for(struct_key, _factory)
+                    # exec_lock spans seen → set_schedule → run → record: a
+                    # shared engine (multi-tenant, same geometry) never has its
+                    # schedule swapped under an in-flight scan, and concurrent
+                    # first-users cannot both count a miss for one compile
+                    with engine.exec_lock:
+                        with span("ferret.schedule"):
+                            cache_hit = self.engine_cache.seen(compile_key)
+                            engine.set_schedule(engine_sched)
+                            state = engine.init_state(
+                                stage_params, opt_states, comp_states,
+                                rings=rings, deltas=deltas,
+                                bounds=bounds, sched_origin=sched_origin,
+                            )
+                        with span("ferret.upload"):
+                            # only this segment's rounds ever reach the
+                            # device: stream residency stays O(segment),
+                            # not O(R)
+                            seg_stream = {k: jnp.asarray(v) for k, v in rows.items()}
+                            if bucket_rounds > seg_len:
+                                # bucket padding: repeat the last item (inert
+                                # schedule rounds never admit it, so
+                                # state/metrics are untouched)
+                                seg_stream = {
+                                    k: jnp.concatenate(
+                                        [v, jnp.repeat(v[-1:], bucket_rounds - seg_len,
+                                                       axis=0)]
+                                    )
+                                    for k, v in seg_stream.items()
+                                }
+                        # overlap: pull segment k+1 on the host while k computes
+                        if R is None or seg_end < R:
+                            nxt = self._segment_end(seg_end, R, events, segment_rounds)
+                            feeder.prefetch(nxt - seg_end)
+                        # segment-constant penalty extras (MAS Ω/ref): re-read
+                        # at every boundary so a re-plan refresh is picked up;
+                        # rides the compiled scan as an argument, never a
+                        # retrace
+                        penalty = (
+                            self._split_penalty_cached(bounds)
+                            if engine.penalty_fn is not None else None
+                        )
+                        try:
+                            with span("ferret.dispatch"):
+                                final_state, ys = self._execute_segment(
+                                    engine, state, seg_stream, supervisor_cfg,
+                                    fault_round, fault_budget_scale, plan, cursor,
+                                    seg_end, budget, penalty, sched_origin=sched_origin,
+                                )
+                            if faults_at_cursor:
+                                # a previously-faulted segment just completed:
+                                # close out its recovery latency
+                                faults_lib.resolved("engine.step")
+                            faults_at_cursor = 0
+                        except (DeviceLossError, TransientFaultError) as e:
+                            # Re-run this segment from the same cursor — state
+                            # is unchanged and the feeder re-serves the retained
+                            # rows, so the stream stays exactly-once. Injected
+                            # faults fire once; a genuine device loss may not
+                            # have gone through a Supervisor, so make sure a
+                            # shrink was requested, and bail out if shrinking
+                            # stops making progress. A transient error re-runs
+                            # at the *same* budget: lost capacity shrinks the
+                            # plan, a hiccup does not.
+                            feeder.rewind()
+                            if fault_round is not None:
+                                pending_faults.remove(fault_round)
+                            num_faults += 1
+                            faults_at_cursor += 1
+                            if (
+                                isinstance(e, DeviceLossError)
+                                and self._pending_budget is None
+                                and self._pending_topology is None
+                            ):
+                                self.fatal_handler(fault_budget_scale)(e)
+                            if faults_at_cursor > _MAX_FAULTS_PER_SEGMENT:
+                                raise
+                            continue
+                        feeder.ack()  # segment complete: retained rows consumed
+                        # account the compile/hit only now: a faulted attempt
+                        # above never compiled, and must not poison the perf
+                        # counters
+                        self.engine_cache.record(compile_key, cache_hit)
+
+                    with span("ferret.fetch") as fetch:
+                        ys = jax.device_get(ys)
+                    # schedule to the results on the host: the dispatch
+                    # returns before the device has run the segment
+                    run_s = fetch.end - schedule.start
+                    ys = {k: v[:seg_len] for k, v in ys.items()}  # drop bucket padding
+                    stage_params = list(final_state.stage_params)
+                    rings = tuple(final_state.rings)
+                    deltas = tuple(final_state.deltas)
+                    opt_states = tuple(final_state.opt_states)
+                    comp_states = tuple(final_state.comp_states)
+                    prev_plan = plan
+                    if self.cfg.profile_feedback and cache_hit:
+                        # online refinement: fold observed wall-clock (cache-hit
+                        # segments only — a compile would swamp the signal) into
+                        # the profile + store; the *next* replan (BudgetEvent,
+                        # request_budget, on_fatal) plans from these numbers
+                        from repro.profile.bridge import observe_segment
+
+                        refined = observe_segment(
+                            self.model_cfg, self.batch, self.seq,
+                            self.profile, plan, bucket_rounds, run_s,
+                        )
+                        if refined is not None:
+                            self.profile = refined[0]
+                            if self.cfg.t_d is None:
+                                self.t_d = planner_lib.default_data_interval(self.profile)
+
+                    acc = np.asarray(ys["acc"], dtype=np.float64)
+                    admitted = np.asarray(ys["admitted"], dtype=np.float64)
+                    result = StreamResult(
+                        online_acc=float(acc.mean()),
+                        online_acc_curve=np.cumsum(acc) / np.arange(1, seg_len + 1),
+                        losses=np.asarray(ys["loss"]),
+                        admitted_frac=float(admitted.mean()),
+                        memory_bytes=plan.memory,
+                        planned_rate=plan.rate,
+                        empirical_rate=empirical_adaptation_rate(self.cfg, plan, admitted, seg_len),
+                        lam_curve=np.asarray(ys["lam"]),
+                        plan=plan,
                     )
-                )
-                acc_all.append(acc)
-                loss_all.append(np.asarray(ys["loss"]))
-                admitted_all.append(admitted)
-                cursor = seg_end
-                # live end-of-segment snapshot: what a graceful drain
-                # checkpoints (save_live_checkpoint) so a restart resumes
-                # from this exact boundary — exactly-once across restarts
-                self._live_resume = ResumeState(
-                    stage_params=list(stage_params),
-                    opt_states=tuple(opt_states),
-                    comp_states=tuple(comp_states),
-                    bounds=list(bounds),
-                    cursor=cursor,
-                    budget_bytes=budget,
-                    rings=tuple(rings),
-                    deltas=tuple(deltas),
-                    sched_origin=int(sched_origin),
-                    geometry=RingGeometry(
-                        ring_size=int(engine_sched.ring_size),
-                        delta_ring=int(engine_sched.delta_ring),
-                    ),
-                )
+                    segments.append(
+                        SegmentReport(
+                            start=cursor, end=seg_end, budget_bytes=budget,
+                            replanned=replanned, replan_s=replan_s, remap_s=remap_s,
+                            run_s=run_s, result=result,
+                            cache_hit=cache_hit, rounds_compiled=bucket_rounds,
+                            take_s=take_s, rounds_lost=seg_rounds_lost,
+                        )
+                    )
+                    acc_all.append(acc)
+                    loss_all.append(np.asarray(ys["loss"]))
+                    admitted_all.append(admitted)
+                    cursor = seg_end
+                    # live end-of-segment snapshot: what a graceful drain
+                    # checkpoints (save_live_checkpoint) so a restart resumes
+                    # from this exact boundary — exactly-once across restarts
+                    self._live_resume = ResumeState(
+                        stage_params=list(stage_params),
+                        opt_states=tuple(opt_states),
+                        comp_states=tuple(comp_states),
+                        bounds=list(bounds),
+                        cursor=cursor,
+                        budget_bytes=budget,
+                        rings=tuple(rings),
+                        deltas=tuple(deltas),
+                        sched_origin=int(sched_origin),
+                        geometry=RingGeometry(
+                            ring_size=int(engine_sched.ring_size),
+                            delta_ring=int(engine_sched.delta_ring),
+                        ),
+                    )
                 # hand the segment to the driver; a _STOP reply ends the
                 # run at this boundary with everything consumed accounted
                 if (yield segments[-1]) is _STOP:

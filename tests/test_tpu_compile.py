@@ -13,6 +13,7 @@ this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,22 @@ def test_iter_fisher_leaf_stats_compiles(one_chip):
         [LEAF] * 4, one_chip,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["compensate", "stats"])
+def test_kernels_carry_their_names_for_the_chip(one_chip, kernel):
+    """Each kernel's name rides its compiled op (``kernel_metadata``),
+    which is the op's text in the profiler's trace."""
+    if kernel == "compensate":
+        fn = lambda g, d, lam: iter_fisher.iter_fisher_compensate_pallas(  # noqa: E731
+            g, d, lam, interpret=False)
+        shapes = [LEAF, (1, *LEAF), ()]
+    else:
+        fn = lambda g, d, vr, va: iter_fisher.iter_fisher_leaf_stats_pallas(  # noqa: E731
+            g, d, vr, va, ALPHA, interpret=False)
+        shapes = [LEAF] * 4
+    text = _compile(fn, shapes, one_chip)
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"iter_fisher_%s"' % kernel, text)
 
 
 @pytest.fixture(scope="module")
