@@ -66,16 +66,17 @@ def init_state(params: Pytree, cfg: CompensationConfig) -> CompensationState:
 
 
 def _update_lambda(
-    state: CompensationState, grad: Pytree, first_delta: Pytree, cfg: CompensationConfig
+    state: CompensationState, grad: Pytree, deltas: Pytree, cfg: CompensationConfig
 ) -> CompensationState:
     """Alg. 1 lines 3–7: one λ-descent step + EMA updates (global λ).
 
-    The whole pytree goes through one packed statistics pass
-    (``repro.kernels.packing``); s1/s2 accumulate as on-device scalars on
-    every path — no per-leaf host round-trips.
+    The statistics use the most recent version step (θ^t − θ^{t-1}), the
+    last row of each leaf's stacked ``deltas``, which the kernels read in
+    place. s1/s2 accumulate as on-device scalars on every path — no
+    per-leaf host round-trips.
     """
     new_vr, new_va, s1_total, s2_total = ops.iter_fisher_stats_tree(
-        grad, first_delta, state.v_r, state.v_a, cfg.alpha
+        grad, deltas, state.v_r, state.v_a, cfg.alpha, row=-1
     )
     grad_lam = -2.0 * s1_total + 2.0 * state.lam * s2_total + 2.0 * cfg.nu * state.lam
     new_lam = state.lam - cfg.eta_lambda * grad_lam
@@ -135,11 +136,8 @@ def compensate(
 
     if method == "iter_fisher":
         if cfg.eta_lambda > 0.0:
-            # Alg. 1 lines 3–7 use the most recent version step (θ^t − θ^{t-1}).
-            last_delta = jax.tree.map(lambda d: d[-1], deltas)
-            state = _update_lambda(state, grad, last_delta, cfg)
-        # One flat-packed pass for the whole pytree (1 kernel launch on the
-        # Pallas path regardless of leaf count).
+            state = _update_lambda(state, grad, deltas, cfg)
+        # Eq. 9 with the λ just updated: a second pass over the whole tree.
         comp = ops.iter_fisher_compensate_tree(grad, deltas, state.lam)
         return state, comp
 

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused Iter-Fisher gradient compensation.
+"""Pallas TPU kernels: fused Iter-Fisher gradient compensation.
 
 The compensation inner loop (Eq. 9) is elementwise over every parameter and
 runs once per stage-update:
@@ -7,9 +7,21 @@ runs once per stage-update:
 
 A naïve XLA lowering materializes τ intermediate g arrays (τ+1 HBM round
 trips). The kernel streams one VMEM tile of g and the τ matching Δθ tiles,
-iterates in registers/VMEM, and writes once: HBM traffic drops from
-(2τ+... ) to (τ+2) array passes and the λ-statistics pass fuses the same
-way. Blocks are (8·128)-aligned 1-D tiles of the flattened parameter.
+iterates in registers/VMEM, and writes once: HBM traffic drops to τ+2 array
+passes, and the λ-statistics pass fuses the same way.
+
+Each kernel runs on one parameter leaf in the leaf's own layout: the leaf's
+major dims collapse to ``(rows, cols) = (prod(shape[:-1]), shape[-1])``
+(0-d and 1-d leaves become ``(1, n)``), which keeps the TPU's tiled HBM
+layout, so XLA passes the leaf in without a copy. The grid walks
+``(tm, tn)`` tiles of about 0.5–2 MiB sized from that shape alone
+(``tile_for``); a ragged last tile is allowed, and results are written in
+the leaf's shape.
+
+Statistics and compensation stay two passes. Eq. 9 uses the λ that Alg. 1
+has just updated from s1 and s2 summed over the whole parameter tree, so no
+leaf can be compensated before every leaf has been read; and for τ ≥ 2 the
+compensation is not linear in λ, so it cannot be finished afterwards.
 
 ``compensate_call`` / ``stats_call`` are the two ``pl.pallas_call``s; the
 per-leaf entry points below and the flat-packed path
@@ -19,7 +31,8 @@ per-leaf entry points below and the flat-packed path
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +40,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
-BLOCK = 4096  # elements per tile (multiple of 8·128 lanes)
-
-# fp32 words of SMEM per λ-statistic output. Grid step i adds into slot
-# i % PARTIAL_SLOTS, so the two outputs take 64 KiB of the v5e's 1 MiB SMEM
-# whatever the buffer length.
-PARTIAL_SLOTS = 8192
+# Scoped VMEM the double-buffered operand tiles of one kernel may take (the
+# v5e default scope is 16 MiB; the rest is left to the compiler), and the
+# largest tile of one operand.
+VMEM_BUDGET = 12 * 2**20
+TILE_BYTES = 2 * 2**20
+LANES = 128
 
 
 def _per_device(call):
@@ -48,56 +61,95 @@ def _per_device(call):
     )
 
 
+def matrix_shape(shape: Tuple[int, ...]) -> Tuple[int, int]:
+    """The 2-D view a kernel takes of a leaf: major dims collapsed."""
+    if len(shape) < 2:
+        return 1, math.prod(shape)
+    return math.prod(shape[:-1]), shape[-1]
+
+
+def tile_for(rows: int, cols: int, operands: int, itemsize: int = 4) -> Tuple[int, int]:
+    """The ``(tm, tn)`` tile of a ``(rows, cols)`` operand, from its shape.
+
+    One fp32 tile holds at most ``min(TILE_BYTES, VMEM_BUDGET / (2 ·
+    operands))`` bytes, so the double-buffered tiles of every operand fit
+    the scoped VMEM. A tile spans whole rows unless ``sub`` of them exceed
+    that; ``tm`` is all rows when they fit, else a multiple of the dtype's
+    sublane count ``sub``, preferring one that divides ``rows``.
+    """
+    sub = 8 * max(1, 4 // itemsize)
+    cap = min(TILE_BYTES, VMEM_BUDGET // (2 * operands)) // 4
+    tn = cols if sub * cols <= cap else max(LANES, cap // sub // LANES * LANES)
+    most = cap // tn
+    if rows <= most:
+        return rows, tn
+    top = max(sub, most // sub * sub)
+    tm = next((t for t in range(top, top // 2, -sub) if rows % t == 0), top)
+    return tm, tn
+
+
+def _grid(shape: Tuple[int, int], tile: Tuple[int, int]) -> Tuple[int, int]:
+    return pl.cdiv(shape[0], tile[0]), pl.cdiv(shape[1], tile[1])
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
+
+
 # ---------------------------------------------------------------------------
 # compensation kernel
 # ---------------------------------------------------------------------------
 
 
-def _compensate_kernel(lam_ref, g_ref, d_ref, o_ref, *, tau: int):
+def _compensate_kernel(lam_ref, g_ref, d_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
-    lam = lam_ref[0].astype(jnp.float32)
-    for i in range(tau):
-        delta = d_ref[i, :].astype(jnp.float32)
-        g = g + lam * g * g * delta
+    lam = lam_ref[0]
+    for i in range(d_ref.shape[0]):
+        g = g + lam * g * g * d_ref[i].astype(jnp.float32)
     o_ref[...] = g.astype(o_ref.dtype)
 
 
 def compensate_call(
-    gf: jax.Array, df: jax.Array, lam: jax.Array, block: int, interpret: bool
+    g: jax.Array,
+    d: jax.Array,
+    lam: jax.Array,
+    interpret: bool,
+    tile: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
-    """Eq. 9 over a flat ``(n,)`` buffer and its ``(τ, n)`` Δθ, n % block == 0."""
-    tau = df.shape[0]
+    """Eq. 9 over a ``(rows, cols)`` operand and its ``(τ, rows, cols)`` Δθ."""
+    tau = d.shape[0]
+    itemsize = min(g.dtype.itemsize, d.dtype.itemsize)
+    tm, tn = tile or tile_for(*g.shape, tau + 2, itemsize)
     call = pl.pallas_call(
-        functools.partial(_compensate_kernel, tau=tau),
-        grid=(gf.shape[0] // block,),
+        _compensate_kernel,
+        grid=_grid(g.shape, (tm, tn)),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),  # λ broadcast to every tile
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((tau, block), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # λ
+            pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
+            pl.BlockSpec((tau, tm, tn), lambda i, j: (0, i, j)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(gf.shape, gf.dtype),
+        out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
+        compiler_params=_params(),
         interpret=interpret,
         name="iter_fisher_compensate",
         metadata={"kernel": "iter_fisher_compensate"},
     )
-    return _per_device(call)(jnp.asarray(lam).reshape(1).astype(jnp.float32), gf, df)
+    return _per_device(call)(jnp.asarray(lam, jnp.float32).reshape(1), g, d)
 
 
 def iter_fisher_compensate_pallas(
     grad: jax.Array, deltas: jax.Array, lam: jax.Array, interpret: bool = False
 ) -> jax.Array:
     """grad: any shape; deltas: (τ, *grad.shape); lam: scalar."""
-    shape = grad.shape
     tau = deltas.shape[0]
     if tau == 0:
         return grad
-    n = grad.size
-    pad = (-n) % BLOCK
-    gf = jnp.pad(grad.reshape(-1), (0, pad))
-    df = jnp.pad(deltas.reshape(tau, -1), ((0, 0), (0, pad)))
-    out = compensate_call(gf, df, lam, BLOCK, interpret)
-    return out[:n].reshape(shape)
+    rows, cols = matrix_shape(grad.shape)
+    out = compensate_call(
+        grad.reshape(rows, cols), deltas.reshape(tau, rows, cols), lam, interpret
+    )
+    return out.reshape(grad.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -105,66 +157,84 @@ def iter_fisher_compensate_pallas(
 # ---------------------------------------------------------------------------
 
 
+def _lane_partial(x: jax.Array) -> jax.Array:
+    """Sum of a ``(tm, tn)`` tile as a ``(1, 128)`` vector."""
+    s = jnp.sum(x, axis=0, keepdims=True)
+    tn = s.shape[1]
+    if tn % LANES:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        return jnp.where(lane == 0, jnp.sum(s), 0.0)
+    out = s[:, :LANES]
+    for k in range(1, tn // LANES):
+        out = out + s[:, k * LANES : (k + 1) * LANES]
+    return out
+
+
 def _stats_kernel(
-    g_ref, d_ref, vr_ref, va_ref, nvr_ref, nva_ref, s1_ref, s2_ref, *, alpha: float, slots: int
+    g_ref, d_ref, vr_ref, va_ref, nvr_ref, nva_ref, s1_ref, s2_ref,
+    *, alpha: float, shape: Tuple[int, int],
 ):
-    # s1/s2 partials are SMEM scalars: Mosaic refuses a 1-element VMEM block.
-    # The grid runs in order ("arbitrary"), so the first `slots` steps zero
-    # their slot and every step accumulates into slot i % slots; the
-    # slots→1 sum happens on device after the call.
-    i = pl.program_id(0)
-    slot = i % slots
-
-    @pl.when(i < slots)
-    def _():
-        s1_ref[slot] = jnp.float32(0.0)
-        s2_ref[slot] = jnp.float32(0.0)
-
+    # Every grid step writes its own s1/s2 partials, so the steps are
+    # independent; the partials are summed on the device after the call.
     g = g_ref[...].astype(jnp.float32)
     d = d_ref[...].astype(jnp.float32)
     vr = vr_ref[...].astype(jnp.float32)
     va = va_ref[...].astype(jnp.float32)
 
     dv_r = (1.0 - alpha) * (g - vr)
-    s1_ref[slot] += jnp.sum(dv_r * va)
-    s2_ref[slot] += jnp.sum(va * va)
+    p1 = dv_r * va
+    p2 = va * va
+    tm, tn = g.shape
+    if shape[0] % tm or shape[1] % tn:
+        # a ragged edge tile reads past the operand: leave that out of the sums
+        r = pl.program_id(0) * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
+        c = pl.program_id(1) * tn + jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1)
+        inside = (r < shape[0]) & (c < shape[1])
+        p1 = jnp.where(inside, p1, 0.0)
+        p2 = jnp.where(inside, p2, 0.0)
+    s1_ref[...] = _lane_partial(p1)
+    s2_ref[...] = _lane_partial(p2)
     nvr_ref[...] = (alpha * vr + (1.0 - alpha) * g).astype(nvr_ref.dtype)
     nva_ref[...] = (alpha * va + (1.0 - alpha) * (g * g * d)).astype(nva_ref.dtype)
 
 
 def stats_call(
-    gf: jax.Array,
-    df: jax.Array,
-    vrf: jax.Array,
-    vaf: jax.Array,
+    g: jax.Array,
+    d: jax.Array,
+    vr: jax.Array,
+    va: jax.Array,
     alpha: float,
-    block: int,
     interpret: bool,
-    out_dtypes: Tuple = (jnp.float32, jnp.float32),
+    row: int = 0,
+    tile: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Alg. 1 λ-statistics over flat ``(n,)`` buffers, n % block == 0.
-    Returns (v_r', v_a', s1, s2) with s1/s2 on-device fp32 scalars."""
-    nb = gf.shape[0] // block
-    slots = min(nb, PARTIAL_SLOTS)
-    tile = pl.BlockSpec((block,), lambda i: (i,))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    """Alg. 1 λ-statistics over ``(rows, cols)`` operands, reading Δθ from
+    row ``row`` of the ``(K, rows, cols)`` stack ``d`` in place. Returns
+    (v_r', v_a', s1, s2), s1/s2 on-device fp32 scalars; v_r' and v_a' take
+    the buffers of v_r and v_a where the caller lets them go."""
+    itemsize = min(a.dtype.itemsize for a in (g, d, vr, va))
+    tm, tn = tile or tile_for(*g.shape, 6, itemsize)
+    grid = _grid(g.shape, (tm, tn))
+    block = pl.BlockSpec((tm, tn), lambda i, j: (i, j))
+    partial = pl.BlockSpec((None, None, 1, LANES), lambda i, j: (i, j, 0, 0))
     call = pl.pallas_call(
-        functools.partial(_stats_kernel, alpha=alpha, slots=slots),
-        grid=(nb,),
-        in_specs=[tile] * 4,
-        out_specs=[tile, tile, smem, smem],
+        functools.partial(_stats_kernel, alpha=alpha, shape=g.shape),
+        grid=grid,
+        in_specs=[block, pl.BlockSpec((None, tm, tn), lambda i, j: (row, i, j)), block, block],
+        out_specs=[block, block, partial, partial],
         out_shape=[
-            jax.ShapeDtypeStruct(gf.shape, out_dtypes[0]),
-            jax.ShapeDtypeStruct(gf.shape, out_dtypes[1]),
-            jax.ShapeDtypeStruct((slots,), jnp.float32),
-            jax.ShapeDtypeStruct((slots,), jnp.float32),
+            jax.ShapeDtypeStruct(g.shape, vr.dtype),
+            jax.ShapeDtypeStruct(g.shape, va.dtype),
+            jax.ShapeDtypeStruct((*grid, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((*grid, 1, LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        input_output_aliases={2: 0, 3: 1},
+        compiler_params=_params(),
         interpret=interpret,
         name="iter_fisher_stats",
         metadata={"kernel": "iter_fisher_stats"},
     )
-    nvr, nva, s1, s2 = _per_device(call)(gf, df, vrf, vaf)
+    nvr, nva, s1, s2 = _per_device(call)(g, d, vr, va)
     return nvr, nva, jnp.sum(s1), jnp.sum(s2)
 
 
@@ -175,16 +245,16 @@ def iter_fisher_leaf_stats_pallas(
     v_a: jax.Array,
     alpha: float,
     interpret: bool = False,
+    row: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One leaf's λ-statistics. ``delta`` has the leaf's shape, or with
+    ``row`` it is the ``(K, *shape)`` Δθ history and its row ``row`` is
+    read in place."""
     shape = grad.shape
-    n = grad.size
-    pad = (-n) % BLOCK
-
-    def flat(a):
-        return jnp.pad(a.reshape(-1).astype(jnp.float32), (0, pad))
-
+    rows, cols = matrix_shape(shape)
+    d = delta.reshape(-1, rows, cols)
     nvr, nva, s1, s2 = stats_call(
-        flat(grad), flat(delta), flat(v_r), flat(v_a), alpha, BLOCK, interpret,
-        out_dtypes=(v_r.dtype, v_a.dtype),
+        grad.reshape(rows, cols), d, v_r.reshape(rows, cols), v_a.reshape(rows, cols),
+        alpha, interpret, row=0 if row is None else row % d.shape[0],
     )
-    return nvr[:n].reshape(shape), nva[:n].reshape(shape), s1, s2
+    return nvr.reshape(shape), nva.reshape(shape), s1, s2

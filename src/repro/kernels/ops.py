@@ -105,9 +105,11 @@ def _tuned():
 def _use_packed() -> bool:
     # Flat-packed single-launch path (repro.kernels.packing). Precedence:
     # REPRO_PACK=1/0 forces either way; else a measured autotune record
-    # for this backend decides; else packed only on real TPU — on CPU
-    # (interpret mode included) the per-leaf path measures ~7× faster
-    # (BENCH_hotpath.json), so guessing "packed" there ships a regression.
+    # for this backend decides; else per leaf on every backend. On a TPU
+    # the pack, the (1, n) view and the unpack are relayout copies of
+    # parameter-sized arrays, which the per-leaf kernels, reading each
+    # leaf in its own layout, avoid; on CPU (interpret mode included) the
+    # per-leaf path measures ~7× faster (BENCH_hotpath.json).
     env = os.environ.get("REPRO_PACK", "").strip()
     if env == "1":
         return True
@@ -116,7 +118,7 @@ def _use_packed() -> bool:
     tuned = _tuned()
     if tuned.pack is not None:
         return bool(tuned.pack)
-    return _use_pallas() and _backend() == "tpu"
+    return False
 
 
 def _pack_block():
@@ -132,8 +134,8 @@ def _pack_block():
 def iter_fisher_compensate(grad: jax.Array, deltas: jax.Array, lam: jax.Array) -> jax.Array:
     """Apply τ iterative Fisher compensations; deltas: (τ, *grad.shape).
 
-    The kernel pads ragged sizes internally, so every leaf takes the fast
-    path (no ``size % 128`` gate).
+    Every leaf, whatever its size, takes the Pallas kernel on that path,
+    in its own layout (``repro.kernels.iter_fisher``).
     """
     if _use_pallas():
         from repro.kernels import iter_fisher as _k
@@ -150,25 +152,32 @@ def iter_fisher_leaf_stats(
     v_r: jax.Array,
     v_a: jax.Array,
     alpha: float,
+    row: Optional[int] = None,
 ):
-    """Per-leaf λ-statistics + EMA updates. Returns (v_r', v_a', s1, s2)."""
+    """Per-leaf λ-statistics + EMA updates. Returns (v_r', v_a', s1, s2).
+
+    With ``row``, ``delta`` is the (K, *grad.shape) Δθ history and its row
+    ``row`` is the Δθ; the kernel reads that row in place.
+    """
     if _use_pallas():
         from repro.kernels import iter_fisher as _k
 
         return _k.iter_fisher_leaf_stats_pallas(
-            grad, delta, v_r, v_a, alpha, interpret=_pallas_interpret()
+            grad, delta, v_r, v_a, alpha, interpret=_pallas_interpret(), row=row
         )
+    if row is not None:
+        delta = delta[row]
     return _ref.iter_fisher_leaf_stats_ref(grad, delta, v_r, v_a, alpha)
 
 
 def iter_fisher_compensate_tree(
     grad, deltas, lam: jax.Array, packed: Optional[bool] = None
 ):
-    """Whole-pytree compensation: one kernel launch regardless of leaf count.
+    """Whole-pytree compensation: one kernel launch per leaf.
 
-    ``packed=None`` honors ``REPRO_PACK`` (default on); ``packed=False``
-    dispatches per leaf (the O(leaves) reference path, kept for
-    benchmarking and cross-checks).
+    ``packed=None`` honors ``REPRO_PACK`` (default off); ``packed=True``
+    packs the tree into one buffer for a single launch
+    (``repro.kernels.packing``).
     """
     if _use_packed() if packed is None else packed:
         from repro.kernels import packing
@@ -181,9 +190,11 @@ def iter_fisher_compensate_tree(
 
 
 def iter_fisher_stats_tree(
-    grad, delta, v_r, v_a, alpha: float, packed: Optional[bool] = None
+    grad, delta, v_r, v_a, alpha: float, packed: Optional[bool] = None,
+    row: Optional[int] = None,
 ):
-    """Whole-pytree λ-statistics: (v_r', v_a', Σ s1, Σ s2), one launch.
+    """Whole-pytree λ-statistics: (v_r', v_a', Σ s1, Σ s2), one launch per
+    leaf (one in all when packed). ``row`` as in ``iter_fisher_leaf_stats``.
 
     Both paths accumulate s1/s2 as on-device fp32 scalars — never as host
     Python floats.
@@ -191,6 +202,8 @@ def iter_fisher_stats_tree(
     if _use_packed() if packed is None else packed:
         from repro.kernels import packing
 
+        if row is not None:
+            delta = jax.tree.map(lambda d: d[row], delta)
         return packing.stats_tree(
             grad, delta, v_r, v_a, alpha,
             use_pallas=_use_pallas(), interpret=_pallas_interpret(),
@@ -203,7 +216,7 @@ def iter_fisher_stats_tree(
         jax.tree.leaves(v_r), jax.tree.leaves(v_a),
     )
     for g, d, vr, va in leaves:
-        nvr, nva, l1, l2 = iter_fisher_leaf_stats(g, d, vr, va, alpha)
+        nvr, nva, l1, l2 = iter_fisher_leaf_stats(g, d, vr, va, alpha, row)
         new_vr.append(nvr)
         new_va.append(nva)
         s1 = s1 + l1

@@ -15,9 +15,12 @@ and norm scales to the jnp reference.  This module removes both costs:
 - ``compensate_tree`` / ``stats_tree`` run the Eq. 9 inner loop and the
   Alg. 1 λ-statistics as **one** ``pl.pallas_call`` each over the packed
   buffer — the λ-statistics s1/s2 block-reduce on-device in the same data
-  pass (per-grid-step partials in SMEM on a sequential grid, plus a tiny
-  on-device epilogue sum).  The kernels themselves are the per-leaf ones
-  of ``repro.kernels.iter_fisher``, launched once over the packed buffer.  When packing is
+  pass (per-grid-step partials, plus a tiny on-device epilogue sum).  The
+  kernels themselves are the per-leaf ones of ``repro.kernels.iter_fisher``,
+  launched once over a ``(1, total)`` view in ``(1, BLOCK)`` tiles.  The
+  engine no longer takes this path by default: the view, the pack and the
+  unpack are relayout copies of parameter-sized arrays on a TPU, which the
+  per-leaf kernels avoid.  When packing is
   forced without Pallas (``REPRO_PACK=1`` on CPU), the same packed buffer
   goes through the jnp reference in one fused elementwise op instead of
   an O(leaves) Python loop.
@@ -37,11 +40,11 @@ import jax.numpy as jnp
 
 from repro.kernels import iter_fisher as _kernels
 from repro.kernels import ref as _ref
-from repro.kernels.iter_fisher import BLOCK  # default tile size for all kernels
 
 Pytree = Any
 
 ALIGN = 8 * 128  # fp32 VPU tile: every leaf starts on an (8, 128) boundary
+BLOCK = 4096  # default grid tile of the packed buffer (a multiple of ALIGN)
 assert BLOCK % ALIGN == 0, "packed grid tile must cover whole leaf slots"
 
 
@@ -179,7 +182,12 @@ def compensate_packed(
     if dflat.shape[0] == 0:
         return gflat
     KERNEL_LAUNCHES += 1
-    return _kernels.compensate_call(gflat, dflat, lam, _resolve_block(block), interpret)
+    n, tau = gflat.shape[0], dflat.shape[0]
+    out = _kernels.compensate_call(
+        gflat.reshape(1, n), dflat.reshape(tau, 1, n), lam, interpret,
+        tile=(1, _resolve_block(block)),
+    )
+    return out.reshape(n)
 
 
 def stats_packed(
@@ -195,9 +203,12 @@ def stats_packed(
     block-reduced on-device in the same pass. Returns (v_r', v_a', s1, s2)."""
     global KERNEL_LAUNCHES
     KERNEL_LAUNCHES += 1
-    return _kernels.stats_call(
-        gflat, dflat, vrflat, vaflat, alpha, _resolve_block(block), interpret
+    n = gflat.shape[0]
+    nvr, nva, s1, s2 = _kernels.stats_call(
+        gflat.reshape(1, n), dflat.reshape(1, 1, n), vrflat.reshape(1, n),
+        vaflat.reshape(1, n), alpha, interpret, tile=(1, _resolve_block(block)),
     )
+    return nvr.reshape(n), nva.reshape(n), s1, s2
 
 
 # ---------------------------------------------------------------------------

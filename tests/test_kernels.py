@@ -6,9 +6,10 @@ Kernels execute under interpret=True on CPU (the TPU path is the same body).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis import given, settings, strategies as st
+import pytest
+from hypothesis import example, given, settings, strategies as st
 
-from repro.kernels import ref
+from repro.kernels import iter_fisher, ref
 from repro.kernels.iter_fisher import (
     iter_fisher_compensate_pallas,
     iter_fisher_leaf_stats_pallas,
@@ -20,42 +21,109 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 # ---------------------------------------------------------------------------
 
 
+# Leaves in their own layouts (collapsed to 2-D by the kernels): a stacked
+# block weight, an MoE-like (experts, d, ff), a matrix, a norm scale, a
+# short vector, a scalar, and a 1-D leaf longer than one row tile.
+LEAVES = [(1, 64, 256), (3, 16, 384), (128, 128), (1, 96), (5,), (), (4500,)]
+
+
+def _leaf_examples(name, values):
+    def wrap(test):
+        for shape in LEAVES:
+            for v in values:
+                test = example(**{"shape": shape, name: v, "dtype": "float32", "seed": 0})(test)
+        return test
+    return wrap
+
+
+@_leaf_examples("tau", [0, 1, 2, 3])
 @settings(max_examples=20, deadline=None)
 @given(
-    n=st.integers(3, 4500),
-    tau=st.integers(1, 6),
+    shape=st.sampled_from(LEAVES + [(33, 17), (2, 3, 130)]),
+    tau=st.integers(0, 6),
     dtype=st.sampled_from(["float32", "bfloat16"]),
     seed=st.integers(0, 2**16),
 )
-def test_iter_fisher_compensate_matches_ref(n, tau, dtype, seed):
+def test_iter_fisher_compensate_matches_ref(shape, tau, dtype, seed):
     rng = np.random.default_rng(seed)
-    g = jnp.asarray(rng.normal(size=(n,)), jnp.dtype(dtype))
-    d = jnp.asarray(rng.normal(size=(tau, n)) * 0.01, jnp.dtype(dtype))
+    g = jnp.asarray(rng.normal(size=shape), jnp.dtype(dtype))
+    d = jnp.asarray(rng.normal(size=(tau, *shape)) * 0.01, jnp.dtype(dtype))
     lam = jnp.asarray(0.2, jnp.float32)
     want = ref.iter_fisher_compensate_ref(g, d, lam)
     got = iter_fisher_compensate_pallas(g, d, lam, interpret=True)
+    assert got.shape == g.shape and got.dtype == g.dtype
     tol = 1e-6 if dtype == "float32" else 3e-2
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol
     )
 
 
+@_leaf_examples("depth", [0, 1, 2, 3])
 @settings(max_examples=15, deadline=None)
 @given(
-    shape=st.sampled_from([(128,), (513,), (32, 33), (4, 8, 130)]),
-    alpha=st.floats(0.5, 0.99),
+    shape=st.sampled_from(LEAVES + [(128,), (513,), (32, 33), (4, 8, 130)]),
+    depth=st.integers(0, 3),
+    dtype=st.just("float32"),
     seed=st.integers(0, 2**16),
 )
-def test_iter_fisher_stats_matches_ref(shape, alpha, seed):
+def test_iter_fisher_stats_matches_ref(shape, depth, dtype, seed):
+    """depth 0: Δθ has the leaf's shape; else it is the newest row of a
+    (depth, *shape) history, read in place."""
     rng = np.random.default_rng(seed)
-    def mk():
-        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+    def mk(*lead):
+        return jnp.asarray(rng.normal(size=(*lead, *shape)), jnp.dtype(dtype))
 
-    g, d, vr, va = mk(), mk(), mk(), mk()
-    want = ref.iter_fisher_leaf_stats_ref(g, d, vr, va, alpha)
-    got = iter_fisher_leaf_stats_pallas(g, d, vr, va, alpha, interpret=True)
+    g, vr, va = mk(), mk(), mk()
+    d = mk(depth) if depth else mk()
+    alpha = float(rng.uniform(0.5, 0.99))
+    want = ref.iter_fisher_leaf_stats_ref(g, d[-1] if depth else d, vr, va, alpha)
+    got = iter_fisher_leaf_stats_pallas(
+        g, d, vr, va, alpha, interpret=True, row=-1 if depth else None
+    )
     for a, b in zip(want, got):
+        assert b.shape == a.shape
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("tile", [(8, 128), (24, 128), (16, 256), (64, 128)])
+def test_iter_fisher_kernels_over_several_grid_steps(tile):
+    """Tiles that split a leaf into several grid steps, ragged ones too:
+    every element compensated once, s1/s2 the reference's sums to fp32
+    rounding."""
+    rows, cols = 100, 300
+    rng = np.random.default_rng(sum(tile))
+    g, vr, va = (jnp.asarray(rng.normal(size=(rows, cols)), jnp.float32) for _ in range(3))
+    d = jnp.asarray(rng.normal(size=(2, rows, cols)) * 0.01, jnp.float32)
+    lam = jnp.asarray(0.3, jnp.float32)
+    got = iter_fisher.compensate_call(g, d, lam, interpret=True, tile=tile)
+    want = ref.iter_fisher_compensate_ref(g, d, lam)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+    got = iter_fisher.stats_call(g, d, vr, va, 0.8, interpret=True, row=1, tile=tile)
+    want = ref.iter_fisher_leaf_stats_ref(g, d[1], vr, va, 0.8)
+    for a, b in zip(want[:2], got[:2]):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-6, atol=1e-6)
+    exact = [np.sum(np.float64(0.2) * (np.float64(g) - vr) * va), np.sum(np.float64(va) ** 2)]
+    for s, e, w in zip(got[2:], exact, want[2:]):
+        # both sums are fp32 sums of rows × cols terms: as close to the
+        # float64 value as the reference's own
+        scale = np.sum(np.abs(np.float64(va)) * (1 + np.abs(np.float64(g) - vr)))
+        assert abs(float(s) - e) <= 4 * np.finfo(np.float32).eps * scale
+        assert abs(float(w) - e) <= 4 * np.finfo(np.float32).eps * scale
+
+
+@pytest.mark.parametrize("leaf", [(1536, 6144), (2048, 1536), (1, 1536), (16000, 2560),
+                                  (3, 16, 384), (1600, 32001), (1, 100000)])
+def test_tiles_fit_the_scoped_vmem(leaf):
+    """The tile comes from the leaf's shape alone: legal TPU block dims
+    (a multiple of the sublane count or the whole dim; a multiple of 128
+    lanes or the whole dim), double-buffered within the budget."""
+    rows, cols = iter_fisher.matrix_shape(leaf)
+    for operands in (3, 4, 6):
+        tm, tn = iter_fisher.tile_for(rows, cols, operands)
+        assert tm == rows or tm % 8 == 0
+        assert tn == cols or tn % 128 == 0
+        assert 2 * operands * tm * tn * 4 <= iter_fisher.VMEM_BUDGET
 
 
 def test_iter_fisher_zero_delta_is_identity():

@@ -97,7 +97,9 @@ def test_scopes_change_only_metadata(toy, monkeypatch):
         if not scoped:
             monkeypatch.setattr(pl.jax, "named_scope", lambda name: contextlib.nullcontext())
         texts.append(compiled_text())
-    assert "ferret." not in texts[1] and texts[0] == texts[1]
+    # a scope name, not "ferret.": the text's stack-frame table may list
+    # core/ferret.py when a trace cached by an earlier test is reused
+    assert not any(scope in texts[1] for scope in SCOPES) and texts[0] == texts[1]
 
 
 def test_kernels_carry_their_names_in_interpret_mode(toy, monkeypatch):

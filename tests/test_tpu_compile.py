@@ -2,8 +2,8 @@
 
 Each case lowers one Iter-Fisher kernel with ``interpret=False`` at
 musicgen-medium width — the packed buffer of one full block (≈ 37.7 M fp32)
-or its largest leaf, the (1536, 6144) MLP weight — and compiles it for one
-chip of a *described* ``v5e:2x2`` topology. Nothing runs: the test proves
+or a leaf in its own layout, or the Iter-Fisher step over one stage — and
+compiles it for one chip of a *described* ``v5e:2x2`` topology. Nothing runs: the test proves
 that the TPU compiler accepts the kernel (block layouts, SMEM/VMEM use) and
 that the executable holds a Mosaic kernel, not an XLA fallback.
 
@@ -12,6 +12,8 @@ process at a time may load the TPU library, and every xdist worker imports
 this file.
 """
 
+import dataclasses
+import math
 import os
 import re
 
@@ -146,3 +148,80 @@ def test_kernels_compile_under_a_four_chip_mesh(four_chip_mesh, kernel):
     with jax.set_mesh(four_chip_mesh):
         text = _compile(fn, shapes, rep)
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# per-leaf kernels in each leaf's own layout
+# ---------------------------------------------------------------------------
+
+# musicgen-medium leaves as a stage holds them (one layer, stacked): an MLP
+# weight, the embedding, a norm scale
+STAGE_LEAVES = [(1, 1536, 6144), (2048, 1536), (1, 1536)]
+K = 2  # the Δθ history of a P = 2 pipeline
+COPIES = re.compile(r"= f32\[([\d,]+)\][^=]* (pad|slice|dynamic-update-slice)\(")
+
+
+def _param_sized_copies(text: str, size: int) -> list:
+    """pad / slice / dynamic-update-slice ops with an output of at least
+    ``size`` elements: the relayout copies of a packed path."""
+    out = []
+    for dims, op in COPIES.findall(text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        if n >= size:
+            out.append((op, dims))
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["compensate", "stats"])
+@pytest.mark.parametrize("leaf", STAGE_LEAVES)
+def test_leaf_kernel_compiles_in_the_leafs_layout(one_chip, leaf, kernel):
+    """One Mosaic kernel on the leaf as it is, results in its shape, and
+    no pad or slice around it."""
+    if kernel == "compensate":
+        fn = lambda g, d, lam: iter_fisher.iter_fisher_compensate_pallas(  # noqa: E731
+            g, d, lam, interpret=False)
+        shapes = [leaf, (K, *leaf), ()]
+    else:
+        fn = lambda g, d, vr, va: iter_fisher.iter_fisher_leaf_stats_pallas(  # noqa: E731
+            g, d, vr, va, ALPHA, interpret=False, row=-1)
+        shapes = [leaf, (K, *leaf), leaf, leaf]
+    text = _compile(fn, shapes, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _param_sized_copies(text, min(1536, math.prod(leaf))) == []
+
+
+def test_compensate_over_a_stage_runs_one_kernel_per_leaf(one_chip, monkeypatch):
+    """``compensation.compensate`` (Iter-Fisher with λ tuning) over one
+    musicgen-medium stage, compiled as the engine runs it on a TPU: one
+    statistics and one compensation kernel per leaf, and no pad, slice or
+    dynamic-update-slice of a parameter-sized array (no packing)."""
+    from repro.core import compensation as comp
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PACK", raising=False)
+    monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    cfg = dataclasses.replace(get_config("musicgen-medium"), num_layers=2)
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    stage = jax.eval_shape(lambda p: T.split_stage_params(cfg, p, [0, 1, 2])[0], params)
+    ccfg = comp.CompensationConfig(method="iter_fisher", eta_lambda=1e-3)
+    state = jax.eval_shape(lambda s: comp.init_state(s, ccfg), stage)
+    deltas = jax.tree.map(lambda p: jax.ShapeDtypeStruct((K, *p.shape), p.dtype), stage)
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    def step(state, grad, deltas):
+        return comp.compensate(ccfg, state, grad, deltas)
+
+    text = jax.jit(step, donate_argnums=0).lower(
+        placed(state), placed(stage), placed(deltas)).compile().as_text()
+    leaves = jax.tree.leaves(stage)
+    assert len(leaves) == 10
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * len(leaves)
+    for name in ("iter_fisher_stats", "iter_fisher_compensate"):
+        assert len(re.findall(r"%%%s(?:\.\d+)? = " % name, text)) == len(leaves)
+    assert _param_sized_copies(text, 1536 * 1536) == []
