@@ -55,8 +55,8 @@ def main(argv=None, root: Path = ROOT, devices_for=None) -> int:
     devices = (devices_for or run_lib.chip_devices)(cell.chips)
     peaks = costs.peaks_for(devices[0].device_kind) if devices_for is None else {}
     with jax.default_matmul_precision("highest"):
-        reference = check.reference_for(spec.reference, cell)
-        variants = {name: check.reference_for(spec.reference, cell, **kw)
+        reference = check.reference_for(cell)
+        variants = {name: check.reference_for(cell, **kw)
                     for name, kw in VARIANTS.items()} if args.control else {}
     spec_check = cell.traffic["check"]
     rows = []

@@ -48,7 +48,7 @@ import dataclasses
 import functools
 import math
 import statistics
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -368,10 +368,8 @@ def judge(values: Dict[str, float], limits: Dict[str, float]) -> Tuple[bool, Dic
     return ok, checks
 
 
-def reference_for(ref_module_loader: Callable, cell, **variant) -> Reference:
-    config = cell.config
-    return Reference(ref_module_loader(config["reference"]), config["model"], cell.traffic,
-                     **variant)
+def reference_for(cell, **variant) -> Reference:
+    return Reference(cell.reference, cell.config["model"], cell.traffic, **variant)
 
 
 def reference_run(reference: Reference, cell, params: Tree, outcome) -> dict:
@@ -382,10 +380,10 @@ def reference_run(reference: Reference, cell, params: Tree, outcome) -> dict:
         return reference.run(params, batches, outcome.phases, loss, change)
 
 
-def compare(ref_module_loader: Callable, cell, params: Tree, outcome) -> Dict[str, float]:
+def compare(cell, params: Tree, outcome) -> Dict[str, float]:
     """Run the reference over the rounds the traffic's ``check`` names and
     read the gaps to what the program reported."""
     with jax.default_matmul_precision("highest"):
-        reference = reference_for(ref_module_loader, cell)
+        reference = reference_for(cell)
     want = reference_run(reference, cell, params, outcome)
     return readings(cell.traffic["check"], outcome.program, want)

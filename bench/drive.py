@@ -33,6 +33,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import check
+import trace_scopes
 from stream_gen import DriftStream
 
 
@@ -126,7 +127,9 @@ class Run:
     compile_window_s: float = 0.0
     plan_memory: float = 0.0  # the largest plan in force in the window
     peak_bytes: int = 0
-    trace: Optional[dict] = None  # bench/trace_reduce.summarize(...)
+    # bench/trace_reduce.summarize(...), and from bench/trace_scopes.summarize(...)
+    # the program's names: run.SCOPED
+    trace: Optional[dict] = None
     segment_s: List[float] = dataclasses.field(default_factory=list)  # wall time of each
     gen_s_per_round: float = 0.0
 
@@ -152,23 +155,14 @@ def prng_key(seed: int, salt: int):
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
-def param_shapes(model: dict) -> dict:
-    d, ff, V, L = model["d_model"], model["d_ff"], model["vocab_size"], model["num_layers"]
-    hd = d // model["num_heads"]
-    q, kv = model["num_heads"] * hd, model["num_kv_heads"] * hd
-    block = {"pre_norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-             "mlp_norm": (d,), "w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
-    return {"embed": (V, d), "blocks": {k: (L, *s) for k, s in block.items()},
-            "final_norm": (d,), "lm_head": (d, V)}
-
-
-def make_params(model: dict, seed: int):
-    """fp32 weights: norms 0 (scale 1 + w), embedding N(0, 0.02), every
-    matrix N(0, 1/fan_in); one jitted call on the default device."""
+def make_params(shapes: dict, seed: int):
+    """fp32 weights of the shapes a reference states (``param_shapes``):
+    norms 0 (scale 1 + w), embedding N(0, 0.02), every other leaf N(0,
+    1/fan_in) with its second-to-last axis the fan-in; one jitted call on
+    the default device."""
     import jax
     import jax.numpy as jnp
 
-    shapes = param_shapes(model)
     flat, treedef = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
 
     def init(key):
@@ -266,26 +260,27 @@ def windowed_source(gen: DriftStream, traffic: dict, seconds: float, keep_rounds
 # ---------------------------------------------------------------------------
 
 
-def program_model_config(config: dict):
+def program_model_config(config: dict, reference):
     """The program's ModelConfig for the configuration file: the registry's
-    architecture at the sizes the file states, which must be the plain
-    decoder block the reference implements."""
+    architecture with every field the reference's ``PROGRAM`` names set
+    from the file, which must then be the block the reference implements
+    (its ``requires``)."""
     import dataclasses as dc
 
     from repro.models.registry import get_config
 
-    m = config["model"]
-    pcfg = dc.replace(
-        get_config(config["registry_name"]), num_layers=m["num_layers"],
-        d_model=m["d_model"], num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        d_ff=m["d_ff"], vocab_size=m["vocab_size"], window=m.get("window"),
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"], head_dim=None,
-        param_dtype=m["param_dtype"], compute_dtype=m["compute_dtype"])
-    block = {"tie_embeddings": False, "qkv_bias": False, "num_experts": 0,
-             "local_global_ratio": 0, "mrope_sections": None, "embed_inputs": True}
-    got = {k: getattr(pcfg, k) for k in block}
-    if got != block or pcfg.family not in ("dense", "audio"):
-        raise ValueError(f"{config['name']}: the program's block {got} is not the decoder stated")
+    m, prog = config["model"], reference.PROGRAM
+    unknown = sorted(set(m) - set(prog["fields"]) - set(prog["optional"]))
+    if unknown:
+        raise ValueError(f"{config['name']}: reference {config['reference']!r} implements "
+                         f"no {unknown}")
+    fields = {k: m[k] for k in prog["fields"]}
+    fields.update({k: m.get(k) for k in prog["optional"]})
+    pcfg = dc.replace(get_config(config["registry_name"]), **fields)
+    got = {k: getattr(pcfg, k) for k in prog["requires"]}
+    if got != prog["requires"]:
+        raise ValueError(f"{config['name']}: the program's block {got} is not the block of "
+                         f"reference {config['reference']!r}, {prog['requires']}")
     return pcfg
 
 
@@ -303,7 +298,7 @@ def make_session(cell, params):
 
         topology = DeviceTopology.discover(max_devices=cell.chips)
     return FerretSession(
-        program_model_config(cell.config), math.inf, tr["algorithm"],
+        program_model_config(cell.config, cell.reference), math.inf, tr["algorithm"],
         batch=tr["batch"], seq=tr["seq"], lr=opt["lr"],
         compensation=CompensationConfig(
             method=comp["method"], lam0=comp["lam0"], alpha=comp["alpha"],
@@ -492,7 +487,7 @@ def execute(cell, seed: int, seconds: float, trace_dir: Optional[Path], t0: floa
     meter = CompileMeter()
     tracer = Tracer(trace_dir)
     tr = cell.traffic
-    params = make_params(cell.config["model"], seed)
+    params = make_params(cell.reference.param_shapes(cell.config["model"]), seed)
     gen = DriftStream.from_traffic(tr, cell.config["model"]["vocab_size"], seed)
     run = Run(cell=cell, devices=list(devices), peaks=peaks)
     session = make_session(cell, params)
@@ -515,7 +510,8 @@ def is_engine_module(name: str) -> bool:
 
 
 def is_kernel_op(hlo: str) -> bool:
-    """A Mosaic (Pallas) kernel, by its HLO instruction: the engine's only
-    ``tpu_custom_call``s are the Iter-Fisher compensation and
-    lambda-statistics kernels."""
-    return 'custom_call_target="tpu_custom_call"' in hlo
+    """An Iter-Fisher kernel (compensation or lambda-statistics), by the
+    name the program gives each launch in its HLO instruction's
+    ``kernel_metadata``; another kernel, or one without a name, is not."""
+    name = trace_scopes.kernel_of(hlo)
+    return name is not None and name.startswith("iter_fisher_")

@@ -9,6 +9,7 @@ def read(run):
     t = run.trace
     if not t or t["kernel_s"] <= 0:
         return None
-    m = run.cell.config["model"]
-    need = costs.iter_fisher_bytes_per_round(m, run.cell.stated["plan"]["bounds"]) * t["rounds"]
+    sizes = run.cell.reference.stage_sizes(run.cell.config["model"],
+                                           run.cell.stated["plan"]["bounds"])
+    need = costs.iter_fisher_bytes_per_round(sizes) * t["rounds"]
     return 100.0 * need / run.peaks["hbm_bytes_per_s"] / t["kernel_s"]

@@ -19,19 +19,112 @@ format's largest value), and so is every gradient flowing back through
 them. Norm statistics, softmax and the loss stay float32.
 
 Parameters are one pytree: ``embed (V, d)``, ``blocks`` with each leaf
-stacked over layers, ``final_norm (d,)`` and ``lm_head (d, V)``. Nothing
-here imports the system under test.
+stacked over layers, ``final_norm (d,)`` and ``lm_head (d, V)``. The head
+size is the configuration's ``head_dim`` where it gives one, else
+``d_model / num_heads``. Nothing here imports the system under test.
+
+Besides ``logits`` and ``loss``, a reference states its block to the
+harness: ``param_shapes`` (the weights the harness makes from the seed),
+``train_flops`` (the model FLOPs of a step, for ``step_mfu``),
+``stage_sizes`` (parameters per pipeline stage, for the Iter-Fisher
+kernels' bytes) and ``PROGRAM`` (the program's configuration of this
+block). A block of another kind is another file that states the same.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 QUANT_MAX = {"float8_e4m3fn": 448.0}
+
+# The program's ModelConfig for this block (``drive.program_model_config``):
+# each key of ``fields`` and ``optional`` in the configuration's "model"
+# sets the ModelConfig field of that name (an optional key left out sets
+# None: the head size then follows d_model / num_heads, and no window
+# means full attention); a key in neither is not this block. Every field
+# or property in ``requires`` must then read the value given: the
+# registry's architecture is otherwise another block than this one.
+PROGRAM = {
+    "fields": ("num_layers", "d_model", "num_heads", "num_kv_heads", "d_ff", "vocab_size",
+               "rope_theta", "norm_eps", "param_dtype", "compute_dtype"),
+    "optional": ("window", "head_dim"),
+    "requires": {"uses_attention": True, "uses_ssm": False, "uses_moe": False,
+                 "local_global_ratio": 0, "tie_embeddings": False, "qkv_bias": False,
+                 "mrope_sections": None, "embed_inputs": True},
+}
+
+
+def head_dim(m: dict) -> int:
+    return m.get("head_dim") or m["d_model"] // m["num_heads"]
+
+
+def attention_shapes(m: dict) -> dict:
+    """One layer's attention weights and the norm before it."""
+    d, hd = m["d_model"], head_dim(m)
+    q, kv = m["num_heads"] * hd, m["num_kv_heads"] * hd
+    return {"pre_norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
+
+
+def stacked(m: dict, block: dict) -> dict:
+    """The whole model's shapes around one layer's: each block leaf stacked
+    over the layers, the embedding, the final norm and the LM head."""
+    d, V, L = m["d_model"], m["vocab_size"], m["num_layers"]
+    return {"embed": (V, d), "blocks": {k: (L, *s) for k, s in block.items()},
+            "final_norm": (d,), "lm_head": (d, V)}
+
+
+def param_shapes(m: dict) -> dict:
+    d, ff = m["d_model"], m["d_ff"]
+    block = dict(attention_shapes(m), mlp_norm=(d,), w_gate=(d, ff), w_up=(d, ff),
+                 w_down=(ff, d))
+    return stacked(m, block)
+
+
+def stack_flops(m: dict, layer_weights: int, rows: int, seq: int) -> float:
+    """Forward + backward FLOPs of one step on rows x seq tokens: 6 per
+    weight per token for every matmul weight a token passes through (each
+    layer's ``layer_weights``, the LM head; the embedding is a gather),
+    plus 12 per (layer, head dim, key) for the full score and value
+    products, as the step computes them (a window masks scores, it does
+    not skip them)."""
+    L, nh, hd = m["num_layers"], m["num_heads"], head_dim(m)
+    tokens = rows * seq
+    weights = L * layer_weights + m["d_model"] * m["vocab_size"]
+    return 6.0 * weights * tokens + 12.0 * L * nh * hd * seq * tokens
+
+
+def attention_weights(m: dict) -> int:
+    """Matmul weights of one layer's attention."""
+    return sum(math.prod(s) for k, s in attention_shapes(m).items() if k != "pre_norm")
+
+
+def train_flops(m: dict, rows: int, seq: int) -> float:
+    return stack_flops(m, attention_weights(m) + 3 * m["d_model"] * m["d_ff"], rows, seq)
+
+
+def split_sizes(shapes: dict, bounds: List[int]) -> List[int]:
+    """Parameters each pipeline stage holds, every leaf counted: the
+    layers between its bounds, the embedding on the first stage, the
+    final norm and LM head on the last."""
+    layer = sum(math.prod(s[1:]) for s in shapes["blocks"].values())
+    sizes = []
+    for j in range(len(bounds) - 1):
+        n = (bounds[j + 1] - bounds[j]) * layer
+        if j == 0:
+            n += math.prod(shapes["embed"])
+        if j == len(bounds) - 2:
+            n += math.prod(shapes["final_norm"]) + math.prod(shapes["lm_head"])
+        sizes.append(n)
+    return sizes
+
+
+def stage_sizes(m: dict, bounds: List[int]) -> List[int]:
+    return split_sizes(param_shapes(m), bounds)
 
 
 def _quantize(x: jax.Array, quant: str) -> jax.Array:
@@ -62,7 +155,7 @@ _activation.defvjp(lambda y, quant: (_quantize(y, quant), None),
                    lambda quant, _, g: (_quantize(g, quant),))
 
 
-def _act(y: jax.Array, quant: Optional[str]) -> jax.Array:
+def act(y: jax.Array, quant: Optional[str]) -> jax.Array:
     return y if quant is None else _activation(y, quant)
 
 
@@ -89,10 +182,11 @@ def _rope(x: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(m: dict, p: dict, h: jax.Array, quant: Optional[str]) -> jax.Array:
+def attention(m: dict, p: dict, h: jax.Array, quant: Optional[str],
+              window: Optional[int]) -> jax.Array:
     b, s, _ = h.shape
     nh, kvh = m["num_heads"], m["num_kv_heads"]
-    hd = m["d_model"] // nh
+    hd = head_dim(m)
     q = _mm("bsd,dq->bsq", h, p["wq"], quant).reshape(b, s, nh, hd)
     k = _mm("bsd,dq->bsq", h, p["wk"], quant).reshape(b, s, kvh, hd)
     v = _mm("bsd,dq->bsq", h, p["wv"], quant).reshape(b, s, kvh, hd)
@@ -102,34 +196,51 @@ def _attention(m: dict, p: dict, h: jax.Array, quant: Optional[str]) -> jax.Arra
     scores = _mm("bqhd,bkhd->bhqk", q, k, quant) / jnp.sqrt(jnp.float32(hd))
     qi, ki = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
     allowed = ki <= qi
-    if m.get("window") is not None:
-        allowed &= ki > qi - m["window"]
+    if window is not None:
+        allowed &= ki > qi - window
     scores = jnp.where(allowed, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = _mm("bhqk,bkhd->bqhd", probs, v, quant).reshape(b, s, nh * hd)
     return _mm("bsq,qd->bsd", out, p["wo"], quant)
 
 
-def _mlp(p: dict, h: jax.Array, quant: Optional[str]) -> jax.Array:
-    gate = _mm("bsd,df->bsf", h, p["w_gate"], quant)
-    up = _mm("bsd,df->bsf", h, p["w_up"], quant)
-    return _mm("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"], quant)
+def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+           quant: Optional[str]) -> jax.Array:
+    gate = _mm("bsd,df->bsf", h, w_gate, quant)
+    up = _mm("bsd,df->bsf", h, w_up, quant)
+    return _mm("bsf,fd->bsd", jax.nn.silu(gate) * up, w_down, quant)
+
+
+def _mlp(m: dict, p: dict, h: jax.Array, quant: Optional[str]) -> jax.Array:
+    return swiglu(h, p["w_gate"], p["w_up"], p["w_down"], quant)
+
+
+def stack_logits(m: dict, params: dict, tokens: jax.Array, quant: Optional[str], mlp,
+                 windows: Sequence[Optional[int]]) -> jax.Array:
+    """The pre-norm stack with layer i's attention under ``windows[i]``
+    and ``mlp(m, p, h, quant)`` as every layer's MLP."""
+    eps = m["norm_eps"]
+    x = act(params["embed"][tokens], quant)
+    for layer in range(m["num_layers"]):
+        p = jax.tree.map(lambda a: a[layer], params["blocks"])
+        h = act(_rms(x, p["pre_norm"], eps), quant)
+        x = act(x + attention(m, p, h, quant, windows[layer]), quant)
+        x = act(x + mlp(m, p, act(_rms(x, p["mlp_norm"], eps), quant), quant), quant)
+    x = act(_rms(x, params["final_norm"], eps), quant)
+    return _mm("bsd,dv->bsv", x, params["lm_head"], quant)
 
 
 def logits(m: dict, params: dict, tokens: jax.Array, quant: Optional[str] = None) -> jax.Array:
-    eps = m["norm_eps"]
-    x = _act(params["embed"][tokens], quant)
-    for layer in range(m["num_layers"]):
-        p = jax.tree.map(lambda a: a[layer], params["blocks"])
-        x = _act(x + _attention(m, p, _act(_rms(x, p["pre_norm"], eps), quant), quant), quant)
-        x = _act(x + _mlp(p, _act(_rms(x, p["mlp_norm"], eps), quant), quant), quant)
-    x = _act(_rms(x, params["final_norm"], eps), quant)
-    return _mm("bsd,dv->bsv", x, params["lm_head"], quant)
+    return stack_logits(m, params, tokens, quant, _mlp, [m.get("window")] * m["num_layers"])
 
 
 def loss(m: dict, params: dict, tokens: jax.Array, labels: jax.Array,
          quant: Optional[str] = None) -> jax.Array:
-    z = logits(m, params, tokens, quant)
+    return cross_entropy(logits(m, params, tokens, quant), labels)
+
+
+def cross_entropy(z: jax.Array, labels: jax.Array) -> jax.Array:
+    """Mean token cross-entropy of logits ``z``."""
     logz = jax.nn.logsumexp(z, axis=-1)
     gold = jnp.take_along_axis(z, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(logz - gold)

@@ -9,7 +9,9 @@ shape the window uses, measures for about ``--seconds``, then compares
 what the timed path reported for the rounds that the traffic's ``check``
 names with the plain reference (``bench/check.py``). With
 ``--trace 1`` the window runs under the profiler and the per-layer
-metrics are reported instead of the end-to-end ones.
+metrics are reported instead of the end-to-end ones; they read the trace
+as ``bench/trace_reduce.py`` reduces it, with the program's scopes,
+kernels and host spans by name from ``bench/trace_scopes.py`` beside it.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (platform, kind, count,
@@ -39,6 +41,8 @@ from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+# what run.trace takes from bench/trace_scopes.summarize
+SCOPED = ("scopes", "model_s", "state_s", "other_s", "kernels", "boundary_s", "switch_stall_s")
 
 
 def parse(argv):
@@ -108,17 +112,22 @@ def main(argv=None, root: Path = ROOT, devices_for=chip_devices, peaks=None) -> 
     log("segment seconds " + " ".join(f"{x:.3f}" for x in run.segment_s))
     if trace_dir is not None:
         import trace_reduce
+        import trace_scopes
 
-        trace = trace_reduce.load(trace_reduce.latest_xplane(str(trace_dir)), drive.is_kernel_op)
-        run.trace = trace_reduce.summarize(
-            trace, is_engine=drive.is_engine_module,
-            rounds_per_run=int(cell.traffic["segment_rounds"]),
-            skip_runs=drive.TRACE_SKIP_RUNS[cell.traffic["runner"]])
-        del trace
+        t_trace = time.perf_counter()
+        path = trace_reduce.latest_xplane(str(trace_dir))
+        window = {"is_engine": drive.is_engine_module,
+                  "rounds_per_run": int(cell.traffic["segment_rounds"]),
+                  "skip_runs": drive.TRACE_SKIP_RUNS[cell.traffic["runner"]]}
+        run.trace = trace_reduce.summarize(trace_reduce.load(path, drive.is_kernel_op), **window)
+        scoped = trace_scopes.summarize(trace_scopes.read(path), **window)
+        if run.trace is not None and scoped is not None:
+            run.trace.update({k: scoped[k] for k in SCOPED})
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(f"trace reduction {time.perf_counter() - t_trace:.3f} s")
 
     t_check = time.perf_counter()
-    values = check.compare(spec.reference, cell, params, outcome)
+    values = check.compare(cell, params, outcome)
     del params
     log(f"reference check {time.perf_counter() - t_check:.3f} s")
     values.update(outcome.exact)

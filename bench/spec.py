@@ -9,7 +9,10 @@ layer, is a reader of its own (``bench/metrics/<name>.py`` with
 returns None), and a configuration names its plain reference
 (``bench/references/<ref>.py``). A per-layer metric lists its cells.
 Adding a cell, a configuration, a traffic mix or a metric is adding files
-and entries; nothing here changes.
+and entries; nothing here changes. A reference states its block too
+(``bench/references/decoder.py`` says what), so a configuration of
+another architecture brings its own reference and the harness stays as
+it is.
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ class Cell:
     stated: dict  # bench/cells/<cell>.json: "plan" (, "low_plan"), "limits"
     end_to_end: List[dict]  # the end-to-end metrics this cell reports
     per_layer: List[dict]  # the per-layer metrics this cell reports
+    reference: ModuleType  # bench/references/<config["reference"]>.py
 
 
 def _load_json(path: Path) -> dict:
@@ -69,7 +73,7 @@ class Spec:
         stated = _load_json(self.bench / "cells" / f"{name}.json")
         per_layer = [m for m in self.doc["per_layer"] if name in m["workloads"]]
         return Cell(name, int(w["chips"]), config, traffic, stated,
-                    list(self.doc["end_to_end"]), per_layer)
+                    list(self.doc["end_to_end"]), per_layer, self.reference(config["reference"]))
 
     def reader(self, metric: str) -> ModuleType:
         return _load_module(self.bench / "metrics" / f"{metric}.py")

@@ -44,9 +44,11 @@ def main(out: str) -> int:
 
     traffic = json.loads((BENCH / "traffic" / "stream.json").read_text())
     traffic.update(batch=4, seq=128, segment_rounds=8, name="stream-small")
-    config = {"name": "small", "registry_name": "musicgen-medium", "model": MODEL}
-    cell = spec_lib.Cell("small.stream", 1, config, traffic, {}, [], [])
-    params = drive.make_params(MODEL, 1)
+    config = {"name": "small", "registry_name": "musicgen-medium", "reference": "decoder",
+              "model": MODEL}
+    decoder = spec_lib.Spec(ROOT, BENCH).reference("decoder")
+    cell = spec_lib.Cell("small.stream", 1, config, traffic, {}, [], [], decoder)
+    params = drive.make_params(decoder.param_shapes(MODEL), 1)
     gen = DriftStream.from_traffic(traffic, MODEL["vocab_size"], 1)
     tmp = tempfile.mkdtemp()
 

@@ -15,9 +15,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import costs
-import drive
 import spec as spec_lib
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, WIDE_ROUTED_MODEL, load_block
 
 
 @pytest.fixture(scope="module")
@@ -37,15 +36,26 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("config", ["musicgen-medium", "h2o-danube-1.8b"])
-def test_train_flops_match_xla(one_chip, config):
-    """One layer at the configuration's widths, 2 rows x 512 tokens, forward
-    and backward of the plain reference in bf16."""
+def _block(name: str):
+    """(reference, model at one layer) of each block the benchmark can
+    state: the decoder at its configurations' widths, and the routed block
+    of ``blocks/routed.py`` at MoE widths with every expert routed, the one
+    size at which its reference (which computes every expert and weights
+    the unrouted ones 0) does the routed work alone."""
     spec = spec_lib.Spec(ROOT, BENCH)
-    model = dict(json.loads((BENCH / "configs" / f"{config}.json").read_text())["model"],
-                 num_layers=1)
-    ref = spec.reference("decoder")
-    shapes = drive.param_shapes(model)
+    if name == "routed":
+        return load_block("routed"), dict(WIDE_ROUTED_MODEL, num_layers=1, num_experts=8,
+                                          experts_per_token=8)
+    model = json.loads((BENCH / "configs" / f"{name}.json").read_text())["model"]
+    return spec.reference("decoder"), dict(model, num_layers=1)
+
+
+@pytest.mark.parametrize("block", ["musicgen-medium", "h2o-danube-1.8b", "routed"])
+def test_train_flops_match_xla(one_chip, block):
+    """One layer, 2 rows x 512 tokens, forward and backward of the block's
+    plain reference in bf16, against its ``train_flops``."""
+    ref, model = _block(block)
+    shapes = ref.param_shapes(model)
     params = jax.tree.map(lambda s: _struct(s, jnp.bfloat16, one_chip), shapes,
                           is_leaf=lambda s: isinstance(s, tuple))
     toks = _struct((2, 512), jnp.int32, one_chip)
@@ -56,8 +66,8 @@ def test_train_flops_match_xla(one_chip, config):
 
     compiled = jax.jit(jax.value_and_grad(loss)).lower(params, toks, toks).compile()
     xla = compiled.cost_analysis()["flops"]
-    ours = costs.decoder_train_flops(model, 2, 512)
-    print(f"{config}: ours {ours:.4e} FLOP, XLA {xla:.4e}")
+    ours = ref.train_flops(model, 2, 512)
+    print(f"{block}: ours {ours:.4e} FLOP, XLA {xla:.4e}")
     assert abs(xla / ours - 1.0) < 0.05
 
 

@@ -1,16 +1,20 @@
 """A whole run of the harness on the CPU at a small size, with the look
-for a chip skipped: cells, configurations, traffic and metrics that exist
-only as new files are found by name, a sound run is correct, and a run
-whose timed path is broken underneath is not."""
+for a chip skipped: cells, configurations, blocks, traffic and metrics
+that exist only as new files are found by name, a sound run is correct,
+and a run whose timed path is broken underneath is not."""
 
 from __future__ import annotations
 
+import filecmp
+import gzip
 import json
+from pathlib import Path
 
 import pytest
 
 import run as run_lib
-from conftest import PEAKS, cpu_devices
+import trace_reduce
+from conftest import BENCH, PEAKS, ROOT, cpu_devices
 
 
 def run_cell(capsys, root, workload, *, seed=11, seconds=1, trace=0) -> dict:
@@ -30,6 +34,44 @@ def test_new_files_are_found_by_name(smoke_root, capsys):
     traced = run_cell(capsys, smoke_root, "smoke.stream", trace=1)
     assert traced["metrics"]["window_rounds"]["value"] > 0
     assert "stream_tokens_per_s" not in traced["metrics"]
+
+
+def test_a_block_added_as_files(smoke_root, capsys, monkeypatch, tmp_path):
+    """The routed block (top-2 of 4 experts, 3 windowed layers to 1 full,
+    head_dim 32 at d_model 64) runs to ``correct`` from files and entries
+    added to the checkout, every file of the benchmark left as it was.
+    Traced, the metric added with it reads the kernels by name. A CPU's
+    trace holds no device, so the trace recorded on one TPU v5e (the
+    engine in 8-round segments, as here) stands in for the profiler's."""
+    for path in BENCH.rglob("*"):
+        rel = path.relative_to(BENCH)
+        if path.is_file() and rel.parts[0] != "tests" and "__pycache__" not in rel.parts:
+            assert filecmp.cmp(path, smoke_root / "bench" / rel, shallow=False), rel
+    before = json.loads((ROOT / "BENCHMARK.json").read_text())
+    after = json.loads((smoke_root / "BENCHMARK.json").read_text())
+    for key in ("configs", "workloads", "end_to_end"):
+        assert after[key][:len(before[key])] == before[key]
+    for old, new in zip(before["per_layer"], after["per_layer"]):
+        assert dict(new, workloads=old["workloads"]) == old
+        assert new["workloads"][:len(old["workloads"])] == old["workloads"]
+
+    out = run_cell(capsys, smoke_root, "routed.stream", seed=2_900_000_015)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["checks"]) == {"loss0_gap", "loss_gap", "plan_departures"}
+    assert set(out["metrics"]) == {"stream_tokens_per_s", "peak_hbm_gib", "setup_s"}
+
+    recorded = tmp_path / "small_scoped.xplane.pb"
+    recorded.write_bytes(gzip.decompress(
+        (Path(__file__).parent / "data" / "small_scoped.xplane.pb.gz").read_bytes()))
+    monkeypatch.setattr(trace_reduce, "latest_xplane", lambda directory: str(recorded))
+    traced = run_cell(capsys, smoke_root, "routed.stream", trace=1)
+    assert traced["correct"] is True, traced["checks"]
+    metrics = {k: v["value"] for k, v in traced["metrics"].items()}
+    # the recorded trace's 2 named kernels on each of its 2 stages, every round
+    assert metrics["kernel_launches_per_round"] == 4.0
+    assert all(metrics[f"engine_{k}_ms_per_round"] > 0 for k in ("model", "state", "other"))
+    assert sum(metrics[f"engine_{k}_ms_per_round"] for k in ("model", "state", "other")) \
+        <= metrics["engine_round_ms"]
 
 
 def test_budget_switch_keeps_every_round(smoke_root, capsys):
@@ -73,7 +115,7 @@ def _half_batch(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [_frozen, _half_batch], ids=["frozen", "half_batch"])
-@pytest.mark.parametrize("workload", ["smoke.stream", "smoke.switch"])
+@pytest.mark.parametrize("workload", ["smoke.stream", "smoke.switch", "routed.stream"])
 def test_broken_timed_path_is_not_correct(smoke_root, capsys, monkeypatch, fault, workload):
     fault(monkeypatch)
     out = run_cell(capsys, smoke_root, workload)

@@ -52,7 +52,10 @@ def test_union_length():
 def test_a_recorded_chip_trace(tmp_path):
     """A trace recorded on one TPU v5e: the engine at a small width
     (d_model 256, 2 layers, P=2), 8-round segments, the trace begun inside
-    a run (so the first is skipped)."""
+    a run (so the first is skipped). It was recorded before the program
+    named its kernels: its Mosaic kernels carry no name, so none counts
+    as an Iter-Fisher kernel, and read as every ``tpu_custom_call`` they
+    are the compensation and lambda-statistics kernels of each stage."""
     import gzip
     from pathlib import Path
 
@@ -64,9 +67,28 @@ def test_a_recorded_chip_trace(tmp_path):
     s = tr.summarize(tr.load(str(path), drive.is_kernel_op), drive.is_engine_module, 8,
                      skip_runs=1)
     assert s["engine_runs"] == 1 and s["rounds"] == 8
-    # compensation and lambda-statistics kernels, for each of 2 stages, every round
-    assert s["kernel_calls"] == 2 * 2 * 8
+    assert s["kernel_calls"] == 0 and s["kernel_s"] == 0
     assert s["window_s"] == pytest.approx(0.004587297, rel=1e-6)
     assert s["busy_s"] == pytest.approx(0.004579986, rel=1e-6)
-    assert s["kernel_s"] == pytest.approx(0.000760428, rel=1e-6)
     assert s["device_ops"][0][0].endswith("[tpu_custom_call]")
+    mosaic = tr.summarize(tr.load(str(path), lambda hlo: "tpu_custom_call" in hlo),
+                          drive.is_engine_module, 8, skip_runs=1)
+    # compensation and lambda-statistics kernels, for each of 2 stages, every round
+    assert mosaic["kernel_calls"] == 2 * 2 * 8
+    assert mosaic["kernel_s"] == pytest.approx(0.000760428, rel=1e-6)
+
+
+@pytest.mark.parametrize("metadata, iter_fisher", [
+    ('kernel_metadata={\n"kernel":"iter_fisher_stats"\n}', True),
+    ('kernel_metadata={"kernel": "iter_fisher_compensate"}', True),
+    ('kernel_metadata={\n"kernel":"flash_attention_fwd"\n}', False),
+    ("kernel_metadata={}", False),
+])
+def test_kernels_by_name(metadata, iter_fisher):
+    """Only a kernel the program names ``iter_fisher_*`` is Iter-Fisher's:
+    a kernel of another name has a roofline of its own to read."""
+    import drive
+
+    hlo = ('%iter_fisher_stats.2 = (f32[8,128]{1,0}) custom-call(f32[8,128]{1,0} %p), '
+           f'custom_call_target="tpu_custom_call", {metadata}')
+    assert drive.is_kernel_op(hlo) is iter_fisher

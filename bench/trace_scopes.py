@@ -29,7 +29,10 @@ whole runs, less ``skip_runs`` at the head), it gives:
 - ``gaps``: each stretch of 1 ms or more with no op on device 0, with the
   ``ferret.*`` host spans overlapping it.
 
-The benchmark's per-layer metrics do not read these yet.
+With ``--trace 1``, ``bench/run.py`` puts ``scopes``, ``model_s``,
+``state_s``, ``other_s``, ``kernels``, ``boundary_s`` and
+``switch_stall_s`` on the run's trace, where a metric's reader finds a
+scope or a kernel by its name.
 """
 
 from __future__ import annotations
